@@ -484,6 +484,9 @@ def main(argv=None) -> None:
                          f"{CHECK_TOLERANCE:g}x")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.check:
         sys.exit(perf_check(args.check))
 

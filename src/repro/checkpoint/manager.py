@@ -31,20 +31,25 @@ import bisect
 import contextlib
 import gc
 import json
+import logging
 import os
 import shutil
 import threading
+import warnings
 from dataclasses import dataclass, replace as _dc_replace
 from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
 
-from repro.transfer.client import MDTPClient, NoTelemetryError, Replica
+from repro.transfer.client import (MDTPClient, NoTelemetryError, Replica,
+                                   TunerFailureWarning)
 from repro.transfer.journal import ResumeJournal, claim_interval
 
 __all__ = ["CheckpointManager", "RestoreOptions", "save_checkpoint",
            "restore_checkpoint", "latest_step"]
+
+log = logging.getLogger(__name__)
 
 _MANIFEST = "manifest.json"
 _DATA = "data.bin"
@@ -588,10 +593,16 @@ def restore_checkpoint(
                             continue    # the manager's shared tuner owns
                             # adaptation via the in-fetch hook
                         try:
-                            dclient.retune(next_wave)
+                            res = dclient.retune(next_wave)
                         except NoTelemetryError:
-                            pass    # wave yielded no live observations; a
-                            # real sweep failure (XlaRuntimeError) propagates
+                            continue    # wave yielded no live observations;
+                            # a real sweep failure (XlaRuntimeError)
+                            # propagates
+                        log.info("between-wave retune at byte %d: C=%d "
+                                 "L=%d, predicted %.4f s for the next "
+                                 "%d bytes", pos, res.params.initial_chunk,
+                                 res.params.large_chunk, res.predicted_time,
+                                 next_wave)
                     else:
                         # per-wave telemetry snapshot from the wave's report.
                         # The tuner is fed HERE only (not via the client's
@@ -603,11 +614,16 @@ def restore_checkpoint(
                         try:
                             new = tuner.update(Telemetry.from_report(
                                 report, dclient.replicas, next_wave))
-                        except Exception:
+                        except Exception as e:
                             # same contract as the client's in-transfer hook:
                             # a failing tuner must never fail a restore whose
                             # waves are streaming fine — keep the current
-                            # geometry and carry on
+                            # geometry, carry on, and say so
+                            warnings.warn(
+                                f"tuner update failed between restore "
+                                f"waves; keeping the current chunk "
+                                f"geometry: {e!r}",
+                                TunerFailureWarning, stacklevel=2)
                             new = None
                         if new is not None:
                             dclient.adopt_params(new)

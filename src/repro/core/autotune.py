@@ -54,6 +54,7 @@ __all__ = [
     "AutotuneResult",
     "GradTuneResult",
     "default_grid",
+    "sweep_call",
     "autotune_chunk_params",
     "autotune_batch",
     "sweep_scenarios",
@@ -163,6 +164,24 @@ def _sized_config(cfg: SimConfig, engine: str, grid, file_size) -> SimConfig:
     return cfg if cfg.max_rounds >= need else cfg._replace(max_rounds=need)
 
 
+def sweep_call(bandwidth, rtt, file_size: int, grid, config: SimConfig,
+               *, n_seeds: int = 1, mode: str = "proportional",
+               engine: str | None = None) -> tuple[tuple, dict]:
+    """Positional arrays and static keywords of the single
+    ``_fused_sweep`` call :func:`autotune_chunk_params` makes — the
+    ``[N]`` rate/RTT vectors, the ``[G]`` grid and the ``[K]`` seeds.
+    Callers that compile or place that program themselves (for another
+    device, or ahead of time) start from here."""
+    engine = resolve_engine(engine, mode)
+    bw, rtt, throttle_t, throttle_bw = _prep(bandwidth, rtt, None, None)
+    cfg = _sized_config(config, engine, grid, file_size)
+    grid_c, grid_l, grid_min = _grid_arrays(grid)
+    seeds = jnp.arange(max(n_seeds, 1))
+    args = (bw, rtt, throttle_t, throttle_bw, jnp.float32(file_size),
+            grid_c, grid_l, grid_min, seeds)
+    return args, {"mode": mode, "config": cfg, "engine": engine}
+
+
 def autotune_chunk_params(
     bandwidth: Sequence[float],
     rtt,
@@ -208,23 +227,14 @@ def autotune_chunk_params(
         with ``n_seeds > 1`` so one unlucky draw doesn't pick the winner.
     """
     grid = list(grid or default_grid())
-    engine = resolve_engine(engine, mode)
-    bw, rtt, throttle_t, throttle_bw = _prep(
-        bandwidth, rtt, None, None)
-    cfg = _sized_config(
+    args, static = sweep_call(
+        bandwidth, rtt, file_size, grid,
         SimConfig(jitter=jitter, pipeline_depth=pipeline_depth,
                   loss_rate=loss_rate, corruption_rate=corruption_rate,
                   hedge_quantile=hedge_quantile,
                   decode_bytes_per_s=decode_bytes_per_s),
-        engine, grid, file_size)
-    grid_c, grid_l, grid_min = _grid_arrays(grid)
-    seeds = jnp.arange(max(n_seeds, 1))
-
-    times_gk = _fused_sweep(
-        bw, rtt, throttle_t, throttle_bw, jnp.float32(file_size),
-        grid_c, grid_l, grid_min, seeds, mode=mode, config=cfg,
-        engine=engine,
-    )
+        n_seeds=n_seeds, mode=mode, engine=engine)
+    times_gk = _fused_sweep(*args, **static)
     times = np.asarray(jnp.mean(times_gk, axis=1), np.float64)
 
     best = int(np.argmin(times))

@@ -210,12 +210,6 @@ def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     ctx = active_ctx()
     if ctx is None:
         return x
-    try:
-        if getattr(jax.typeof(x), "vma", None):
-            return x
-    except Exception:
-        pass
-    from repro.compat import any_axis_bound
-    if any_axis_bound(ctx.mesh.axis_names):
+    if jax.typeof(x).vma or jax.sharding.get_abstract_mesh().manual_axes:
         return x
     return jax.lax.with_sharding_constraint(x, ctx.sharding(logical))

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.common import ModelConfig
 from repro.models.layers import apply_norm
 from repro.models.transformer import _apply_block, _positions_embed, program_for
@@ -136,7 +135,7 @@ def make_pp_forward(cfg: ModelConfig, mesh, n_microbatches: int,
     def forward(params, tokens):
         x = _positions_embed(cfg, params, tokens)
         xs = _split_microbatches(x, M)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             pipelined, mesh=mesh,
             in_specs=(P(stage_axis), P()),
             out_specs=(P(stage_axis), P(stage_axis)),

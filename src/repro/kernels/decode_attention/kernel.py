@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 __all__ = ["decode_attention_bkv"]
 
 _NEG_INF = -1e30
@@ -98,7 +96,7 @@ def decode_attention_bkv(
     scale: float,
     window: int | None = None,
     blk_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     BKV, G, hd = q.shape
     Sk = k.shape[1]
@@ -126,7 +124,7 @@ def decode_attention_bkv(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos.reshape(1).astype(jnp.int32), q, k, v)

@@ -39,7 +39,7 @@ def decode_attention(
     scale: Optional[float] = None,
     window: Optional[int] = None,
     blk_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, _, H, hd = q.shape
     S_max, KV = k_cache.shape[1], k_cache.shape[2]

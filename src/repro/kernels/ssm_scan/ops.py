@@ -26,7 +26,7 @@ def ssm_scan(
     *,
     chunk: int = 128,
     head_block: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, S, H, P = x.shape
     pad_s = (-S) % chunk

@@ -212,8 +212,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     print(ma)
-    from repro.compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
 
     hlo = compiled.as_text()

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distributed.context import active_ctx, constrain
 from repro.models.common import ModelConfig, ParamSpec
 
@@ -213,7 +212,7 @@ def moe_block(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.A
         n_shards=ctx.axis_size("model"), fsdp_axes=fsdp_axes,
     )
     if wg is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda xt_, gv_, gi_, wi_, wo_: local(xt_, gv_, gi_, wi_, None, wo_),
             mesh=mesh,
             in_specs=(P((*batch_axes, "model"), None), P((*batch_axes, "model"), None),
@@ -222,7 +221,7 @@ def moe_block(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.A
         )
         yt = fn(xt, gate_vals, gate_idx, wi, wo)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P((*batch_axes, "model"), None), P((*batch_axes, "model"), None),

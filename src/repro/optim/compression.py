@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_mean",
            "compressed_reduce_scatter", "make_compressed_allreduce"]
@@ -47,7 +46,7 @@ def compressed_mean(local: Any, axis_names) -> Any:
     shard_map manual over those axes)."""
     n = 1
     for a in (axis_names if isinstance(axis_names, tuple) else (axis_names,)):
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
 
     def one(x):
         q, scale = quantize_int8(x)
@@ -79,7 +78,7 @@ def compressed_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     Returns this device's f32 shard of the mean: shape [size/N] of the
     flattened input (input is zero-padded to a multiple of N).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     q, scale = quantize_int8(x)
     flat = q.reshape(-1)
@@ -116,7 +115,7 @@ def make_compressed_allreduce(mesh, data_axes=("data", "pod"),
             return meaned
 
         spec = P()  # grads replicated over data axes after reduction
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=jax.tree.map(lambda _: P(*[None]), grads),
             out_specs=jax.tree.map(lambda _: P(*[None]), grads),

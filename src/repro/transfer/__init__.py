@@ -18,6 +18,7 @@ _EXPORTS = {
     "MDTPClient": ".client", "ClientOptions": ".client",
     "Replica": ".client", "TransferReport": ".client",
     "TransferIncompleteError": ".client", "fetch_blob": ".client",
+    "TunerFailureWarning": ".client",
     "ResumeJournal": ".journal", "claim_interval": ".journal",
     "merge_intervals": ".journal", "uncovered_intervals": ".journal",
     "FleetModel": ".manager", "TransferJob": ".manager",

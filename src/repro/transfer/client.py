@@ -56,6 +56,7 @@ import asyncio
 import contextlib
 import random
 import time
+import warnings
 from dataclasses import dataclass, field, replace as _dc_replace
 from typing import Optional, Sequence
 
@@ -97,6 +98,13 @@ class NoTelemetryError(RuntimeError):
     ``RuntimeError`` — which would also swallow real failures like
     jax's ``XlaRuntimeError`` from the fused sweep itself.
     """
+
+
+class TunerFailureWarning(RuntimeWarning):
+    """A tuner update raised (a failed jit compile, a tuner bug).  The
+    transfer keeps its current chunk geometry and its bytes keep flowing;
+    the warning and :attr:`TransferReport.tuner_errors` are how the
+    failure stays visible."""
 
 
 class TransferIncompleteError(IOError):
@@ -267,6 +275,12 @@ class TransferReport:
     #: connection — so each losing copy is charged the bytes it actually
     #: received before the race resolved, not its whole range.
     hedge_wasted_bytes: int = 0
+    #: tuner updates that raised during ``fetch(tuner=...)`` (each one
+    #: also warns :class:`TunerFailureWarning`).  After the first, the
+    #: tuner is not consulted again in this fetch, so this is 0 or 1;
+    #: ``tuner_error`` is the ``repr`` of the exception ("" = none).
+    tuner_errors: int = 0
+    tuner_error: str = ""
 
     @property
     def throughput(self) -> float:
@@ -628,7 +642,7 @@ class MDTPClient:
         tune_every = tune_interval_bytes or max(
             size // 8, 2 * sched.params.large_chunk)
         tune_state = {"bytes": sched.done_bytes, "t": t0, "busy": False,
-                      "task": None}
+                      "task": None, "errors": 0, "error": ""}
 
         def _failed_names() -> list:
             """Retired replica names in retirement order, deduped — the
@@ -676,11 +690,18 @@ class MDTPClient:
                                                      telemetry)
                 except asyncio.CancelledError:
                     raise
-                except Exception:
+                except Exception as e:
                     # a failing tuner path (the lazy online import in a
                     # jax-less deployment, a bad jit compile, a tuner
                     # bug) must never fail a transfer whose bytes are
-                    # flowing fine — keep the current geometry, carry on
+                    # flowing fine — keep the current geometry, carry
+                    # on, stop consulting the tuner, and say so
+                    tune_state["errors"] += 1
+                    tune_state["error"] = repr(e)
+                    warnings.warn(
+                        f"tuner update failed; keeping the current chunk "
+                        f"geometry for the rest of this fetch: {e!r}",
+                        TunerFailureWarning, stacklevel=2)
                     new = None
                 tune_state["bytes"] = sched.done_bytes
                 tune_state["t"] = time.monotonic()
@@ -1022,6 +1043,7 @@ class MDTPClient:
                     journal.record(offset + start, ndata, crc)
                 if (tuner is not None and sched.done_bytes < size
                         and not tune_state["busy"]
+                        and not tune_state["errors"]
                         and sched.done_bytes - tune_state["bytes"]
                         >= tune_every):
                     # fire-and-forget: the triggering lane keeps fetching
@@ -1233,6 +1255,8 @@ class MDTPClient:
             hedges_issued=sched.hedges_issued,
             hedges_won=sched.hedges_won,
             hedge_wasted_bytes=sched.hedge_wasted,
+            tuner_errors=tune_state["errors"],
+            tuner_error=tune_state["error"],
         )
         self.last_report = report
         return buf, report

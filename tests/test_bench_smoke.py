@@ -16,12 +16,17 @@ _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 @pytest.fixture(scope="module")
 def bench_run(tmp_path_factory):
-    json_path = tmp_path_factory.mktemp("bench") / "BENCH_autotune.json"
+    out_dir = tmp_path_factory.mktemp("bench")
+    json_path = out_dir / "BENCH_autotune.json"
+    # a fresh compile cache per run: the bench's cold-compile rows stay
+    # cold, and the repo's own cache directory is left alone
+    env = dict(os.environ,
+               JAX_COMPILATION_CACHE_DIR=str(out_dir / "jax_cache"))
     res = subprocess.run(
         [sys.executable, "-m", "benchmarks.run",
          "--skip", "fig2", "fig3", "fig4", "fig5", "table2", "roofline",
          "restore", "--json", str(json_path)],
-        capture_output=True, text=True, cwd=_ROOT, timeout=420,
+        capture_output=True, text=True, cwd=_ROOT, timeout=420, env=env,
     )
     return res, json_path
 

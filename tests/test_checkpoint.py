@@ -328,3 +328,116 @@ def test_elastic_restore_resharding(tmp_path):
     assert _trees_equal(state, restored)
     assert restored["w"].sharding.spec == jax.sharding.PartitionSpec(
         "data", "model")
+
+
+def test_wave_tuner_failure_warns_and_restore_completes(tmp_path):
+    """A tuner that raises between restore waves keeps the bytes flowing
+    (the wave loop keeps its geometry) and says so with a warning."""
+    from repro.transfer import TunerFailureWarning
+
+    class ExplodingTuner:
+        def update(self, t):
+            raise RuntimeError("sweep refused")
+
+    state = {"w": jax.random.normal(jax.random.PRNGKey(8), (512, 512))}
+    d = save_checkpoint(str(tmp_path), 700, state)
+    s = RangeServer(throttle=Throttle(bytes_per_s=50 * MB)).start()
+    base = "/ckpt/step_0000000700"
+    s.add_file(base + "/manifest.json", os.path.join(d, "manifest.json"))
+    s.add_file(base + "/data.bin", os.path.join(d, "data.bin"))
+    try:
+        total = os.path.getsize(os.path.join(d, "data.bin"))
+        with pytest.warns(TunerFailureWarning, match="sweep refused"):
+            restored, _ = restore_checkpoint(
+                str(tmp_path), state, step=700,
+                replicas=[Replica("127.0.0.1", s.port, "/ckpt")],
+                tuner=ExplodingTuner(), wave_bytes=total // 2 + 1)
+        assert _trees_equal(state, restored)
+    finally:
+        s.stop()
+
+
+# -------------------------------------------- chip_smoke.py, rehearsed on CPU
+
+_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+
+def _chip_smoke():
+    """``chip_smoke.py`` (repo root, not a package) as a module."""
+    import importlib.util
+    import sys
+
+    mod = sys.modules.get("chip_smoke")
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules["chip_smoke"] = mod
+        spec.loader.exec_module(mod)
+    return mod
+
+
+def _smoke_cfg(**kw):
+    """Qwen3's reduced config with a vocab wide enough (about 50 MB of
+    bf16) that each of four waves spans several default-size chunks, so
+    every mirror serves data and the stopped one dies mid-range."""
+    from repro.configs.qwen3_1_7b import reduced
+    return reduced().replace(vocab_size=393216, **kw)
+
+
+def test_chip_smoke_rehearsal_bf16_restore():
+    """The smoke's own restore at reduced width on the CPU: a bf16
+    ``init_params`` tree served by three throttled mirrors, the slowest
+    stopped mid-restore, grid retunes between waves, every leaf bit-exact
+    against ``data.bin`` and against the original, and the sweep's argmin
+    agreeing across placements."""
+    smoke = _chip_smoke()
+    seen = {}
+    res = smoke.smoke(_smoke_cfg(), seed=3, rates=[8 * MB, 16 * MB, 24 * MB],
+                      rtt=0.03, emit=seen.__setitem__)
+    run = res["restore"]
+    assert run.stopped == 0
+    assert all(b > 0 for b in run.served), run.served
+    assert len(run.retunes) >= 2 and not run.tuner_failures
+    assert res["shards"] == 13                 # one per leaf, one device
+    assert res["rel"] == 0.0
+    assert seen["tuner_choice_device_cpu"]
+
+
+def test_chip_smoke_main_refuses_without_tpu():
+    """The smoke never falls back to the CPU."""
+    with pytest.raises(SystemExit) as e:
+        _chip_smoke().main([])
+    assert "no TPU" in str(e.value.code)
+
+
+_SHARDED_SMOKE = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, {tests!r})
+from test_checkpoint import MB, _chip_smoke, _smoke_cfg
+from repro.launch.mesh import make_local_mesh
+res = _chip_smoke().smoke(
+    _smoke_cfg(n_kv_heads=4), seed=5, rates=[8 * MB, 16 * MB, 24 * MB],
+    rtt=0.03, mesh=make_local_mesh(data=1, model=4), emit=lambda *a: None)
+assert res["split"] == 8 and res["shards"] == 13 * 4, res
+assert res["restore"].stopped == 0
+print("SHARDED_OK")
+"""
+
+
+def test_chip_smoke_rehearsal_sharded_four_devices():
+    """``--chips 4``'s path on four virtual CPU devices (a subprocess: the
+    device count is fixed when JAX starts): heads, mlp and vocab split 4
+    ways, norms replicated, every shard bit-exact against ``data.bin``."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH=os.path.join(_ROOT, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    prog = _SHARDED_SMOKE.format(tests=os.path.dirname(__file__))
+    res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert "SHARDED_OK" in res.stdout

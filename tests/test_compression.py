@@ -63,7 +63,7 @@ _MULTIDEV_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.optim.compression import (
         compressed_mean, compressed_reduce_scatter)
 

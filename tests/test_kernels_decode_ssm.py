@@ -46,7 +46,7 @@ def _close(a, b, tol):
 def test_decode_head_dims(hd, dtype):
     q, kc, vc = _decode_case(hd=hd, dtype=dtype)
     pos = jnp.int32(300)
-    out = decode_attention(q, kc, vc, pos, blk_k=128)
+    out = decode_attention(q, kc, vc, pos, blk_k=128, interpret=True)
     _close(out, _decode_ref(q, kc, vc, pos),
            2e-2 if dtype == jnp.bfloat16 else 2e-5)
 
@@ -55,7 +55,7 @@ def test_decode_head_dims(hd, dtype):
 def test_decode_gqa_ratios(G):
     q, kc, vc = _decode_case(G=G)
     pos = jnp.int32(511)  # full cache valid
-    out = decode_attention(q, kc, vc, pos, blk_k=256)
+    out = decode_attention(q, kc, vc, pos, blk_k=256, interpret=True)
     _close(out, _decode_ref(q, kc, vc, pos), 2e-2)
 
 
@@ -63,7 +63,8 @@ def test_decode_gqa_ratios(G):
 def test_decode_positions(pos):
     """Block skipping must be exact at block boundaries and tiny caches."""
     q, kc, vc = _decode_case()
-    out = decode_attention(q, kc, vc, jnp.int32(pos), blk_k=256)
+    out = decode_attention(q, kc, vc, jnp.int32(pos), blk_k=256,
+                           interpret=True)
     _close(out, _decode_ref(q, kc, vc, jnp.int32(pos)), 2e-2)
 
 
@@ -71,7 +72,8 @@ def test_decode_positions(pos):
 def test_decode_sliding_window(window):
     q, kc, vc = _decode_case(S=1024)
     pos = jnp.int32(900)
-    out = decode_attention(q, kc, vc, pos, blk_k=256, window=window)
+    out = decode_attention(q, kc, vc, pos, blk_k=256, window=window,
+                           interpret=True)
     _close(out, _decode_ref(q, kc, vc, pos, window=window), 2e-2)
 
 
@@ -79,7 +81,7 @@ def test_decode_ragged_cache_padding():
     """Cache length not a multiple of blk_k pads and stays exact."""
     q, kc, vc = _decode_case(S=700)
     pos = jnp.int32(600)
-    out = decode_attention(q, kc, vc, pos, blk_k=256)
+    out = decode_attention(q, kc, vc, pos, blk_k=256, interpret=True)
     _close(out, _decode_ref(q, kc, vc, pos), 2e-2)
 
 
@@ -104,7 +106,7 @@ def test_decode_matches_model_decode_attention():
     q, _, _ = _qkv(p, cfg, x, x, pos[None], pos[None], True)
     q = q.reshape(B, 1, cfg.n_heads, cfg.hd)
     out = decode_attention(q, k2, v2, pos, blk_k=128,
-                           scale=1.0 / math.sqrt(cfg.hd))
+                           scale=1.0 / math.sqrt(cfg.hd), interpret=True)
     y = jnp.einsum("bsnh,nhd->bsd", out.reshape(B, 1, cfg.n_heads, cfg.hd),
                    p["wo"])
     _close(y, y_ref, 3e-2)
@@ -125,14 +127,14 @@ def _ssm_case(B=2, S=256, H=8, P=32, N=16, dtype=jnp.bfloat16):
 @pytest.mark.parametrize("chunk", [32, 64, 128])
 def test_ssm_chunk_sweep(chunk):
     args = _ssm_case()
-    y = ssm_scan(*args, chunk=chunk, head_block=4)
+    y = ssm_scan(*args, chunk=chunk, head_block=4, interpret=True)
     _close(y, ssm_scan_ref(*args), 2e-2)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ssm_dtypes(dtype):
     args = _ssm_case(dtype=dtype)
-    y = ssm_scan(*args, chunk=64)
+    y = ssm_scan(*args, chunk=64, interpret=True)
     _close(y, ssm_scan_ref(*args), 2e-2 if dtype == jnp.bfloat16 else 2e-4)
 
 
@@ -140,13 +142,14 @@ def test_ssm_dtypes(dtype):
 def test_ssm_head_shapes(HP):
     H, P = HP
     args = _ssm_case(H=H, P=P)
-    y = ssm_scan(*args, chunk=64, head_block=min(4, H))
+    y = ssm_scan(*args, chunk=64, head_block=min(4, H),
+                 interpret=True)
     _close(y, ssm_scan_ref(*args), 2e-2)
 
 
 def test_ssm_ragged_seq():
     args = _ssm_case(S=200)
-    y = ssm_scan(*args, chunk=64)
+    y = ssm_scan(*args, chunk=64, interpret=True)
     _close(y, ssm_scan_ref(*args), 2e-2)
 
 
@@ -154,7 +157,7 @@ def test_ssm_state_continuity():
     """Chunk boundaries must carry exact state: one long scan == the
     reference sequential recurrence everywhere, including the tail."""
     args = _ssm_case(S=512)
-    y = ssm_scan(*args, chunk=128)
+    y = ssm_scan(*args, chunk=128, interpret=True)
     ref = ssm_scan_ref(*args)
     _close(y[:, -32:], ref[:, -32:], 2e-2)
 
@@ -164,5 +167,5 @@ def test_ssm_matches_model_ssd():
     from repro.models.ssm import _ssd_chunked
     x, dt, A, Bm, Cm = _ssm_case(S=256)
     y_model, _ = _ssd_chunked(x, dt, A, Bm, Cm, chunk=64)
-    y_kernel = ssm_scan(x, dt, A, Bm, Cm, chunk=64)
+    y_kernel = ssm_scan(x, dt, A, Bm, Cm, chunk=64, interpret=True)
     _close(y_kernel, y_model.astype(y_kernel.dtype), 2e-2)

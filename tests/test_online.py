@@ -35,7 +35,7 @@ from repro.core.online import (  # noqa: E402
     tune_chunk_params_mcgrad,
 )
 from repro.transfer import RangeServer, Replica, Throttle  # noqa: E402
-from repro.transfer.client import MDTPClient  # noqa: E402
+from repro.transfer.client import MDTPClient, TunerFailureWarning  # noqa: E402
 
 MB = 1024 * 1024
 GB = 1024 * MB
@@ -306,10 +306,13 @@ def test_fetch_tuner_exception_does_not_fail_transfer():
                 raise RuntimeError("tuner boom")
 
         client = MDTPClient(replicas, tuner=ExplodingTuner())
-        buf, report = asyncio.run(client.fetch(
-            len(blob), tune_interval_bytes=MB))
+        with pytest.warns(TunerFailureWarning, match="tuner boom"):
+            buf, report = asyncio.run(client.fetch(
+                len(blob), tune_interval_bytes=MB))
         assert bytes(buf) == blob
         assert report.retunes == 0
+        assert report.tuner_errors == 1
+        assert "tuner boom" in report.tuner_error
     finally:
         for s in servers:
             s.stop()

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis_dict
 from repro.configs import get_config
 from repro.models.layers import attention, attention_specs
 from repro.models.common import init_params
@@ -40,6 +39,6 @@ def test_windowed_slice_fewer_flops():
         return jax.jit(lambda x: attention(
             p, cfg, x, causal=True, window=64, q_block=qb))
 
-    fl_win = cost_analysis_dict(run(128).lower(x).compile())["flops"]
-    fl_ref = cost_analysis_dict(run(1024).lower(x).compile())["flops"]
+    fl_win = run(128).lower(x).compile().cost_analysis()["flops"]
+    fl_ref = run(1024).lower(x).compile().cost_analysis()["flops"]
     assert fl_win < fl_ref * 0.5, (fl_win, fl_ref)
