@@ -42,6 +42,7 @@ from typing import Any, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.transfer.client import (MDTPClient, NoTelemetryError, Replica,
                                    TunerFailureWarning)
 from repro.transfer.journal import ResumeJournal, claim_interval
@@ -280,9 +281,11 @@ class _StreamingRestore:
             # never hand XLA a view of the spool mmap we intend to unmap.
             arr = arr.copy()
         shd = self._shards[j]
-        self._out[self._slot_of[j]] = (
-            jax.device_put(arr, shd) if shd is not None
-            else jax.device_put(arr))
+        with obs.span("mdtp.device_put", leaf=e["key"],
+                      bytes=int(e["nbytes"])):
+            self._out[self._slot_of[j]] = (
+                jax.device_put(arr, shd) if shd is not None
+                else jax.device_put(arr))
 
     def finish(self, require_all: bool = True) -> Any:
         """Assemble the restored pytree.  ``require_all=False`` is the
@@ -347,14 +350,15 @@ def _finish_restore(stream: _StreamingRestore, jr, spool: Optional[str],
     scratch state (journal + spool) once every leaf is safely on device —
     ``device_put`` dispatch is async, so block before unmapping the spool
     the arrays were read from."""
-    state = stream.finish(require_all)
-    if jr is not None:
-        jax.block_until_ready(state)
-        jr.complete()
-        stream.close()
-        if spool is not None:
-            with contextlib.suppress(OSError):
-                os.remove(spool)
+    with obs.span("mdtp.finish"):
+        state = stream.finish(require_all)
+        if jr is not None:
+            jax.block_until_ready(state)
+            jr.complete()
+            stream.close()
+            if spool is not None:
+                with contextlib.suppress(OSError):
+                    os.remove(spool)
     return state
 
 
@@ -507,11 +511,12 @@ def restore_checkpoint(
         grid_retune = tuner is None and getattr(manager, "tuner", None) is None
 
         async def run():
-            async with client_for(
-                    [Replica(r.host, r.port, r.path + "/" + _MANIFEST)
-                     for r in base]) as mclient:
-                msize = await mclient.blob_size()
-                mbuf, _ = await mclient.fetch(msize)
+            with obs.span("mdtp.manifest"):
+                async with client_for(
+                        [Replica(r.host, r.port, r.path + "/" + _MANIFEST)
+                         for r in base]) as mclient:
+                    msize = await mclient.blob_size()
+                    mbuf, _ = await mclient.fetch(msize)
             manifest = json.loads(bytes(mbuf).decode())
             total = int(manifest["total_bytes"])
             lo, hi = 0, total
@@ -540,8 +545,9 @@ def restore_checkpoint(
                 jr = ResumeJournal.open(
                     os.path.join(resume, "journal.log"),
                     total_bytes=total, meta={"step": int(step)})
-            stream = _StreamingRestore(manifest, like, shardings,
-                                       spool_path=spool)
+            with obs.span("mdtp.buffer", bytes=total):
+                stream = _StreamingRestore(manifest, like, shardings,
+                                           spool_path=spool)
             if mirror is not None:
                 # peer-assisted broadcast: landed ranges become servable
                 # to other restorers while this restore is in flight
@@ -576,15 +582,20 @@ def restore_checkpoint(
                 # protocol: ranges are received straight into its buffer
                 if not wave_bytes or wave_bytes >= span:
                     if span > 0:
-                        await dclient.fetch(span, sink=stream, offset=lo,
-                                            tuner=tuner, resume=jr)
+                        with obs.span("mdtp.wave", wave=0, bytes=span):
+                            await dclient.fetch(span, sink=stream,
+                                                offset=lo, tuner=tuner,
+                                                resume=jr)
                     return _finish_restore(stream, jr, spool, require_all)
                 pos = lo
+                wave = 0
                 while pos < hi:
                     n = min(int(wave_bytes), hi - pos)
-                    _, report = await dclient.fetch(n, sink=stream,
-                                                    offset=pos, resume=jr)
+                    with obs.span("mdtp.wave", wave=wave, bytes=n):
+                        _, report = await dclient.fetch(
+                            n, sink=stream, offset=pos, resume=jr)
                     pos += n
+                    wave += 1
                     if pos >= hi:
                         break
                     next_wave = min(int(wave_bytes), hi - pos)
@@ -593,7 +604,8 @@ def restore_checkpoint(
                             continue    # the manager's shared tuner owns
                             # adaptation via the in-fetch hook
                         try:
-                            res = dclient.retune(next_wave)
+                            with obs.span("mdtp.retune"):
+                                res = dclient.retune(next_wave)
                         except NoTelemetryError:
                             continue    # wave yielded no live observations;
                             # a real sweep failure (XlaRuntimeError)
@@ -629,7 +641,8 @@ def restore_checkpoint(
                             dclient.adopt_params(new)
             return _finish_restore(stream, jr, spool, require_all)
 
-        return asyncio.run(run()), step
+        with obs.span("mdtp.restore", step=int(step)):
+            return asyncio.run(run()), step
 
     with open(os.path.join(d, _MANIFEST)) as f:
         manifest = json.load(f)

@@ -212,6 +212,7 @@ class _VirtualRangeClient(MDTPClient):
                                   progress=None):
                 parts = []
                 nbytes, elapsed, rtt_inc = 0, 0.0, False
+                header_wait = body_read = 0.0
                 if progress is not None and len(progress) > 1:
                     # wire-send stamp (see _Conn.fetch_range): the first
                     # piece's request goes out immediately below
@@ -236,6 +237,8 @@ class _VirtualRangeClient(MDTPClient):
                         # layer's landed-fraction check
                         progress[0] = nbytes
                     elapsed += reply.elapsed
+                    header_wait += reply.header_wait
+                    body_read += reply.body_read
                     rtt_inc = rtt_inc or reply.rtt_included
                     if reply.nbytes < take:
                         break   # short piece: stop — later pieces would
@@ -243,6 +246,8 @@ class _VirtualRangeClient(MDTPClient):
                     pos += take
                 data = (into[:nbytes] if into is not None
                         else b"".join(parts))
-                return _RangeReply(data, nbytes, elapsed, rtt_inc)
+                return _RangeReply(data, nbytes, elapsed, rtt_inc,
+                                   header_wait=header_wait,
+                                   body_read=body_read)
 
         return _VConn(replica, request_latency=self.request_latency)

@@ -60,13 +60,15 @@ import warnings
 from dataclasses import dataclass, field, replace as _dc_replace
 from typing import Optional, Sequence
 
+from repro import obs
 from repro.core.chunking import ChunkParams, default_chunk_params
 from repro.core.throughput import make_estimator, rtt_corrected_bandwidth
 from repro.transfer.journal import merge_intervals
 from repro.transfer.sched import ChunkScheduler, defaults as sched_defaults
 # _Conn/_RangeReply re-exported here: the data pipeline and the fleet
 # manager import them from this module (their historical home)
-from repro.transfer.transport import _Conn, _RangeReply, _crc32_async
+from repro.transfer.transport import (_Conn, _RangeReply, _crc32_async,
+                                      _crc32_timed_async)
 
 __all__ = ["Replica", "ClientOptions", "TransferReport", "MDTPClient",
            "NoTelemetryError", "TransferIncompleteError", "fetch_blob",
@@ -262,9 +264,6 @@ class TransferReport:
     #: bytes satisfied from the resume journal instead of the wire
     #: (``fetch(resume=...)``); 0 for fresh transfers.
     resumed_bytes: int = 0
-    #: seconds spent re-verifying journaled range checksums during resume
-    #: replay (large records hash in the executor); 0.0 for fresh fetches.
-    resume_verify_seconds: float = 0.0
     #: endgame hedges (``hedge_quantile`` > 0): speculative duplicate
     #: fetches issued for straggling in-flight ranges, and how many beat
     #: their original copy to completion.
@@ -589,6 +588,16 @@ class MDTPClient:
             stripe=stripe, trace=self._sched_trace)
         hedge_q = sched.hedge_quantile
         refresh_s = sched.refresh_s
+        # this fetch's counters (``repro.obs``), None unless recording
+        rec = obs.current()
+        st = (rec.fetch([r.name for r in self.replicas], sched.params)
+              if rec is not None else None)
+        crc32 = _crc32_async
+        if st is not None:
+            async def crc32(data) -> int:
+                crc, secs = await _crc32_timed_async(data)
+                st.crc_s += secs
+                return crc
 
         lock = asyncio.Lock()
         #: signalled whenever reclaimed work appears or in-flight bytes
@@ -598,7 +607,6 @@ class MDTPClient:
         #: surviving taker — the mirror-death fault-tolerance contract).
         cond = asyncio.Condition(lock)
         resumed_bytes = 0
-        resume_verify = 0.0
 
         if journal is not None:
             # Replay: every journaled record inside this window whose
@@ -615,16 +623,15 @@ class MDTPClient:
                 return None
 
             verified: list[tuple[int, int]] = []
-            t_verify = time.monotonic()
-            for s_abs, nb, rcrc in journal.records():
-                if s_abs < offset or s_abs + nb > offset + size:
-                    continue
-                v = _view_of(s_abs, nb)
-                if v is not None and rcrc is not None \
-                        and await _crc32_async(v) != rcrc:
-                    continue
-                verified.append((s_abs - offset, nb))
-            resume_verify = time.monotonic() - t_verify
+            with obs.span("mdtp.resume_verify"):
+                for s_abs, nb, rcrc in journal.records():
+                    if s_abs < offset or s_abs + nb > offset + size:
+                        continue
+                    v = _view_of(s_abs, nb)
+                    if v is not None and rcrc is not None \
+                            and await _crc32_async(v) != rcrc:
+                        continue
+                    verified.append((s_abs - offset, nb))
             covered = merge_intervals(verified)
             resumed_bytes = sched.seed_resume(covered)
             if sink_commit is not None:
@@ -751,15 +758,18 @@ class MDTPClient:
                 hedge_broke.add(hedger)
                 c.abort()
 
-        async def _reclaim(start: int, length: int, ban: frozenset, *,
-                           count: bool, lost: int = 0) -> None:
-            """Return an owed range to the scheduler atomically, waking
-            parked lanes, then perform whatever healing/cancellation it
-            prescribes (a settled range heals the winner's bytes back; a
-            duplicate still racing the reclaimed range is aborted)."""
+        async def _reclaim(i: int, start: int, length: int, ban: frozenset,
+                           *, count: bool, lost: int = 0) -> None:
+            """Return a range replica ``i`` owed to the scheduler
+            atomically, waking parked lanes, then perform whatever
+            healing/cancellation it prescribes (a settled range heals the
+            winner's bytes back; a duplicate still racing the reclaimed
+            range is aborted)."""
             async with lock:
                 res = sched.on_reclaim(start, length, ban,
                                        count=count, lost=lost)
+                if st is not None:
+                    st.replicas[i].settled()
                 if res.heal is not None and buf is not None:
                     buf[start:start + len(res.heal)] = res.heal
                 cond.notify_all()
@@ -801,7 +811,7 @@ class MDTPClient:
             for sample in conn.take_rtt_samples():
                 sched.observe_rtt(j, sample)
             body = scratch[:ndata] if zero_copy else reply.data
-            crc = await _crc32_async(body) if need_crc else None
+            crc = await crc32(body) if need_crc else None
             if verify and reply.crc32 is not None and crc != reply.crc32:
                 async with lock:
                     dead = sched.on_hedge_corrupt(j, start)
@@ -924,6 +934,8 @@ class MDTPClient:
                         # lock sections — go around and re-evaluate
                         continue
                     start, length, ban, prog = asn
+                    if st is not None:
+                        st.replicas[i].assigned()
                 # destination: straight into the assembly buffer / the
                 # sink's own storage (zero-copy), or per-chunk scratch
                 # for callable sinks / the legacy copy path.  A raising
@@ -939,7 +951,7 @@ class MDTPClient:
                         mv = (memoryview(bytearray(length))
                               if zero_copy else None)
                 except BaseException:
-                    await _reclaim(start, length, ban, count=False)
+                    await _reclaim(i, start, length, ban, count=False)
                     raise
                 try:
                     reply = await conn.fetch_range(
@@ -947,13 +959,13 @@ class MDTPClient:
                         into=mv, progress=prog)
                 except (ConnectionError, OSError,
                         asyncio.IncompleteReadError) as e:
-                    await _reclaim(start, length, ban, count=True,
+                    await _reclaim(i, start, length, ban, count=True,
                                    lost=getattr(e, "partial_bytes", 0))
                     return "broken"
                 except BaseException:
                     # cancellation / unexpected error: release the range
                     # so peers waiting on in-flight work aren't stranded
-                    await _reclaim(start, length, ban, count=False)
+                    await _reclaim(i, start, length, ban, count=False)
                     raise
                 try:
                     ndata = reply.nbytes
@@ -964,7 +976,7 @@ class MDTPClient:
                         # off the event loop for big bodies; the range is
                         # exclusively ours until committed or re-pooled,
                         # so hashing it unlocked is safe
-                        crc = await _crc32_async(reply.data)
+                        crc = await crc32(reply.data)
                     if (verify and reply.crc32 is not None
                             and crc != reply.crc32):
                         # corrupt body: the bytes never count — the
@@ -974,6 +986,8 @@ class MDTPClient:
                         async with lock:
                             res = sched.on_corrupt(i, start, length, ban,
                                                    ndata)
+                            if st is not None:
+                                st.replicas[i].settled()
                             if res.heal is not None and buf is not None:
                                 buf[start:start + len(res.heal)] = \
                                     res.heal
@@ -1023,10 +1037,13 @@ class MDTPClient:
                     # e.g. the user-supplied sink raised (disk full): the
                     # bytes were NOT delivered — reclaim the whole range
                     # and settle the in-flight count before propagating
-                    await _reclaim(start, length, ban, count=False)
+                    await _reclaim(i, start, length, ban, count=False)
                     raise
                 async with lock:
                     res = sched.on_commit(i, start, length, ban, ndata)
+                    if st is not None:
+                        st.replicas[i].committed(reply.header_wait,
+                                                 reply.body_read)
                     if res.heal is not None and buf is not None:
                         # a hedge beat this body to completion: heal the
                         # winner's bytes over this landing (the duplicate
@@ -1120,6 +1137,8 @@ class MDTPClient:
                 async with lock:
                     sched.on_replica_death(i)
                     cond.notify_all()
+                if st is not None and i in sched.failed:
+                    st.replicas[i].failed_at = time.monotonic()
 
         async def _refresh_coverage(j: int) -> None:
             """Background poller for partial mirror ``j``: HEAD its
@@ -1193,6 +1212,8 @@ class MDTPClient:
                 with contextlib.suppress(asyncio.CancelledError):
                     await clock
         t_end = time.monotonic()
+        if st is not None:
+            st.end = t_end
         # settle an in-flight tuner update BEFORE any raise, so no task
         # outlives the event loop: drain it on success (its adoption
         # isn't lost; transfer time excludes it), cancel it on failure
@@ -1251,7 +1272,6 @@ class MDTPClient:
             retries_per_replica=retries_per,
             corrupt_ranges=corrupt_per,
             resumed_bytes=resumed_bytes,
-            resume_verify_seconds=resume_verify,
             hedges_issued=sched.hedges_issued,
             hedges_won=sched.hedges_won,
             hedge_wasted_bytes=sched.hedge_wasted,
@@ -1259,6 +1279,8 @@ class MDTPClient:
             tuner_error=tune_state["error"],
         )
         self.last_report = report
+        if st is not None:
+            st.report = report
         return buf, report
 
     async def blob_size(self) -> int:

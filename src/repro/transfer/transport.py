@@ -33,7 +33,7 @@ from typing import NamedTuple, Optional
 from repro.transfer import codec
 from repro.transfer.sched import defaults as sched_defaults
 
-__all__ = ["_Conn", "_RangeReply", "_crc32_async"]
+__all__ = ["_Conn", "_RangeReply", "_crc32_async", "_crc32_timed_async"]
 
 #: bodies at or below this size are CRC'd inline on the event loop (the
 #: executor round-trip costs more than the hash); larger bodies hash in
@@ -53,6 +53,21 @@ async def _crc32_async(data) -> int:
         return zlib.crc32(data)
     return await asyncio.get_running_loop().run_in_executor(
         None, zlib.crc32, data)
+
+
+def _crc32_timed(data) -> tuple[int, float]:
+    t0 = time.monotonic()
+    crc = zlib.crc32(data)
+    return crc, time.monotonic() - t0
+
+
+async def _crc32_timed_async(data) -> tuple[int, float]:
+    """:func:`_crc32_async` that also returns the seconds the hash took,
+    timed on the thread that ran it and handed back with the CRC."""
+    if len(data) <= _CRC_INLINE_MAX:
+        return _crc32_timed(data)
+    return await asyncio.get_running_loop().run_in_executor(
+        None, _crc32_timed, data)
 
 
 class _RangeReply(NamedTuple):
@@ -79,6 +94,10 @@ class _RangeReply(NamedTuple):
     #: ``wire_bytes`` — feeding decoded bytes into a bandwidth estimator
     #: over a compressed path would double-count the codec's savings.
     wire_nbytes: Optional[int] = None
+    #: seconds from the connection's turn to read this reply to its
+    #: parsed header, and from there to the body's last wire byte
+    header_wait: float = 0.0
+    body_read: float = 0.0
 
     @property
     def wire_bytes(self) -> int:
@@ -487,9 +506,10 @@ class _Conn:
                 raise ConnectionError("pipelined predecessor failed")
             t_ready = time.monotonic()
             code, headers = await self._read_headers()
+            t_head = time.monotonic()
             if not pipelined:
                 # idle-pipe turnaround = request RTT + server think time
-                self._rtt_samples.append(time.monotonic() - t_send)
+                self._rtt_samples.append(t_head - t_send)
             if code not in (200, 206):
                 raise ConnectionError(f"HTTP {code}")
             try:
@@ -535,7 +555,8 @@ class _Conn:
                 elapsed=t_end - (t_ready if pipelined else t_send),
                 rtt_included=not pipelined,
                 crc32=self._parse_checksum(headers),
-                wire_nbytes=wire_n)
+                wire_nbytes=wire_n,
+                header_wait=t_head - t_ready, body_read=t_end - t_head)
         except BaseException:
             self.broken = True
             raise

@@ -114,3 +114,11 @@ def test_sched_contract_is_registered():
     banned = lc.CONTRACTS["repro.transfer.sched"]
     for must in ("asyncio", "socket", "jax"):
         assert must in banned
+
+
+def test_obs_contract_is_registered():
+    # the recorder promises to load no JAX and no I/O stack of its own
+    lc = _layercheck()
+    assert "repro.obs" in lc.CONTRACTS
+    for must in ("asyncio", "socket", "jax"):
+        assert must in lc.CONTRACTS["repro.obs"]

@@ -1,0 +1,146 @@
+"""The in-program recorder (``repro.obs``): off, it costs a shared no-op
+and records nothing; on, spans nest with their intervals and attributes,
+fetches attach to the span they ran in, and an active JAX profiler trace
+holds the spans on its host plane."""
+
+from __future__ import annotations
+
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import pytest
+
+from repro import obs
+
+
+def test_off_span_is_the_shared_no_op_and_nothing_is_recorded():
+    assert obs.current() is None
+    a, b = obs.span("mdtp.restore"), obs.span("mdtp.wave", wave=0)
+    assert a is b
+    with a as entered:
+        assert entered is a
+    with obs.recording() as rec:
+        pass
+    assert rec.spans == [] and rec.fetches == []
+    assert obs.current() is None
+
+
+def test_on_spans_nest_with_intervals_and_attributes():
+    with obs.recording() as rec:
+        assert obs.current() is rec
+        with obs.span("mdtp.restore", step=7) as restore:
+            for k in range(2):
+                with obs.span("mdtp.wave", wave=k, bytes=100 + k):
+                    f = rec.fetch(["a:1", "b:2"], params="C,L")
+                    with obs.span("mdtp.device_put", leaf=f"w{k}"):
+                        time.sleep(0.002)
+            with obs.span("mdtp.finish"):
+                pass
+    assert obs.current() is None
+    assert rec.spans == [restore]
+    assert restore.attrs == {"step": 7}
+    assert [c.name for c in restore.children] == \
+        ["mdtp.wave", "mdtp.wave", "mdtp.finish"]
+    waves = rec.find("mdtp.wave")
+    assert [w.attrs for w in waves] == [{"wave": 0, "bytes": 100},
+                                        {"wave": 1, "bytes": 101}]
+    assert [w.fetch for w in waves] == rec.fetches
+    assert [r.name for r in waves[0].fetch.replicas] == ["a:1", "b:2"]
+    assert waves[1].fetch.params == "C,L"
+    puts = restore.find("mdtp.device_put")
+    assert [p.attrs["leaf"] for p in puts] == ["w0", "w1"]
+    for outer, inner in [(restore, waves[0]), (waves[0], puts[0]),
+                         (waves[1], puts[1])]:
+        assert outer.start <= inner.start <= inner.end <= outer.end
+    assert all(p.seconds >= 0.002 for p in puts)
+    assert waves[0].end <= waves[1].start
+
+
+def test_a_fetch_outside_any_span_is_kept_by_the_record():
+    with obs.recording() as rec:
+        f = rec.fetch(["a:1"])
+    assert rec.fetches == [f] and rec.spans == []
+
+
+def test_in_flight_time_counts_only_between_zero_and_one_outstanding():
+    r = obs.ReplicaStats("a:1")
+    r.assigned()
+    r.assigned()                  # a second range: no new interval
+    time.sleep(0.01)
+    r.committed(0.001, 0.004)
+    assert r.outstanding == 1 and r.inflight_s == 0.0
+    r.settled()                   # given back: the interval closes
+    assert r.outstanding == 0 and r.inflight_s >= 0.01
+    assert r.first_commit is not None and r.first_commit == r.last_commit
+    assert (r.header_wait_s, r.body_read_s) == (0.001, 0.004)
+    f = obs.Fetch(start=r.first_commit - 1.0, replicas=[r],
+                  end=r.first_commit)
+    assert r.alive_s(f) == pytest.approx(1.0)
+    r.failed_at = f.start + 0.25
+    assert r.alive_s(f) == pytest.approx(0.25)
+
+
+def test_recording_does_not_nest():
+    with obs.recording():
+        with pytest.raises(RuntimeError):
+            with obs.recording():
+                pass
+    assert obs.current() is None
+
+
+def test_importing_obs_loads_no_jax():
+    code = ("import sys; import repro.obs; "
+            "print(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules))")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env={"PYTHONPATH": src, "PATH": ""})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
+
+
+def test_an_active_profiler_trace_holds_the_spans_on_the_host_plane(
+        tmp_path):
+    """The pattern of the chip benchmark's CPU trace test: record a trace
+    with spans inside, read it back, find them on the host plane with the
+    nesting and lengths the record has."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    d = str(tmp_path)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        with obs.recording() as rec:
+            with obs.span("mdtp.restore"):
+                with obs.span("mdtp.wave", wave=0, bytes=123):
+                    time.sleep(0.05)
+                with obs.span("mdtp.device_put", leaf="w"):
+                    f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(Path(d).rglob("*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("mdtp."):
+                    found[ev.name] = (plane.name, ev.start_ns,
+                                      ev.start_ns + ev.duration_ns)
+    assert set(found) == {"mdtp.restore", "mdtp.wave", "mdtp.device_put"}
+    assert all(p.startswith("/host:") for p, _, _ in found.values())
+    _, r0, r1 = found["mdtp.restore"]
+    for name in ("mdtp.wave", "mdtp.device_put"):
+        _, s0, s1 = found[name]
+        assert r0 <= s0 <= s1 <= r1
+    for s in rec.find("mdtp.wave") + rec.find("mdtp.restore"):
+        _, s0, s1 = found[s.name]
+        assert (s1 - s0) / 1e9 == pytest.approx(s.seconds, abs=2e-3)

@@ -45,6 +45,12 @@ CONTRACTS = {
         "repro.transfer.client", "repro.transfer.server",
         "repro.transfer.manager", "repro.transfer.transport",
     ),
+    # the in-program recorder: stdlib only, so the scheduling core or a
+    # JAX-less deployment can record without loading the stack
+    "repro.obs": (
+        "asyncio", "socket", "selectors", "ssl",
+        "jax", "jaxlib",
+    ),
 }
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
