@@ -32,6 +32,7 @@ import contextlib
 import gc
 import json
 import logging
+import mmap
 import os
 import shutil
 import threading
@@ -124,15 +125,19 @@ def latest_step(root: str) -> Optional[int]:
 class _StreamingRestore:
     """Range sink for ``MDTPClient.fetch``: overlap network with H2D.
 
-    Ranges land in a preallocated buffer, and the moment the last byte of
-    a leaf's range arrives that leaf is ``device_put`` — so host→device
-    transfers of early leaves run while later leaves are still on the
-    wire, instead of serially after the whole blob is buffered.
+    Ranges land in one buffer of the whole blob, and the moment the last
+    byte of a leaf's range arrives that leaf is ``device_put`` — so
+    host→device transfers of early leaves run while later leaves are
+    still on the wire, instead of serially after the whole blob is
+    buffered.  In memory the buffer is an anonymous private map, which
+    costs nothing to allocate: the kernel zero-fills each page on its
+    first touch, so the receive that fills a page also faults it in.  A
+    crash-resumable restore lands in a file-backed spool map instead.
 
     Implements the client's **zero-copy sink protocol**
     (``writable(start, length) -> memoryview`` + ``commit(start,
     nbytes)``): the transfer layer receives socket bytes directly into
-    this sink's preallocated blob buffer, so the restore path is
+    this sink's blob buffer, so the restore path is
     copy-free from socket to leaf buffer (the only remaining move is the
     inherent host→device ``device_put``).  The legacy ``sink(start,
     data)`` callable is kept (write-then-commit) for callers that hold
@@ -162,15 +167,18 @@ class _StreamingRestore:
         self._mmap = None
         self._spool_file = None
         if spool_path is None or total == 0:
-            self._buf = bytearray(total)
+            # allocated untouched (mmap(2) maps no page).  Not self._mmap,
+            # which is the spool's: that would copy every leaf out and
+            # unmap in close().  This map lives as long as the leaf views
+            # and device_put inputs that refer to it
+            self._buf = (mmap.mmap(-1, total, flags=mmap.MAP_PRIVATE)
+                         if total else bytearray())
         else:
             # crash-resumable restore: the landing buffer is a file-backed
             # mmap, so bytes that reached the page cache (and were then
             # journaled + fsync'd by the client) survive a process death.
             # An existing spool's content is preserved — the resume path
             # re-verifies journaled CRCs against exactly these bytes.
-            import mmap
-
             f = open(spool_path, "a+b")
             try:
                 f.seek(0, os.SEEK_END)

@@ -357,6 +357,134 @@ def test_wave_tuner_failure_warns_and_restore_completes(tmp_path):
         s.stop()
 
 
+# ------------------------- the landing buffer, an untouched anonymous map
+
+def _serve(d, step, rates):
+    """One throttled ``RangeServer`` per rate, serving step ``step``."""
+    servers = []
+    for bw in rates:
+        s = RangeServer(throttle=Throttle(bytes_per_s=bw)).start()
+        base = f"/ckpt/step_{step:010d}"
+        s.add_file(base + "/manifest.json", os.path.join(d, "manifest.json"))
+        s.add_file(base + "/data.bin", os.path.join(d, "data.bin"))
+        servers.append(s)
+    return servers
+
+
+def _landing_state():
+    return {"params": {"w": jax.random.normal(jax.random.PRNGKey(12),
+                                              (512, 512)),
+                       "b": jnp.arange(4096, dtype=jnp.float32)},
+            "emb": jax.random.normal(jax.random.PRNGKey(13), (256, 384)),
+            "step": jnp.int32(3)}
+
+
+def test_streamed_restore_into_the_untouched_map_is_byte_identical(
+        tmp_path):
+    """Three waves over two mirrors land bit-exact in the anonymous map,
+    whose pages the receive faults in as it fills them."""
+    from repro import obs
+
+    state = _landing_state()
+    d = save_checkpoint(str(tmp_path), 21, state)
+    total = os.path.getsize(os.path.join(d, "data.bin"))
+    servers = _serve(d, 21, (30 * MB, 60 * MB))
+    try:
+        replicas = [Replica("127.0.0.1", s.port, "/ckpt") for s in servers]
+        with obs.recording() as rec:
+            restored, _ = restore_checkpoint(
+                str(tmp_path), state, step=21, replicas=replicas,
+                wave_bytes=total // 3 + 1)
+            restored = jax.block_until_ready(restored)
+    finally:
+        for s in servers:
+            s.stop()
+    assert _trees_equal(state, restored)
+    [buf] = rec.find("mdtp.buffer")
+    assert buf.attrs["bytes"] == total
+    waves = rec.find("mdtp.wave")
+    assert len(waves) == 3
+    assert sum(w.attrs["bytes"] for w in waves) == total
+
+
+@pytest.mark.parametrize("landing", ["memory", "spool"])
+def test_landing_buffer_is_a_map_of_the_blob(tmp_path, landing):
+    """In memory the blob lands in an anonymous private map, kept out of
+    ``_mmap`` (the spool's, whose leaves are copied out); a spool lands
+    in its file map as before.  Both give the same leaves."""
+    import mmap
+
+    from repro.checkpoint.manager import _DATA, _MANIFEST, _StreamingRestore
+
+    state = _landing_state()
+    d = save_checkpoint(str(tmp_path), 22, state)
+    manifest = json.load(open(os.path.join(d, _MANIFEST)))
+    blob = open(os.path.join(d, _DATA), "rb").read()
+    spool = str(tmp_path / "spool.bin") if landing == "spool" else None
+    stream = _StreamingRestore(manifest, state, spool_path=spool)
+    assert isinstance(stream._buf, mmap.mmap)
+    assert len(stream._buf) == len(blob)
+    assert (stream._mmap is None) == (landing == "memory")
+    stream.sink(0, blob)
+    restored = jax.block_until_ready(stream.finish())
+    assert _trees_equal(state, restored)
+    stream.close()
+
+
+def test_sharded_restore_lands_only_its_own_span(tmp_path):
+    """A sharded restore fetches ``[lo, hi)`` of its host into the
+    blob-sized map and restores the leaves of that span."""
+    from repro.transfer.shard import manifest_boundaries, plan_shards
+
+    state = _landing_state()
+    d = save_checkpoint(str(tmp_path), 23, state)
+    manifest = json.load(open(os.path.join(d, "manifest.json")))
+    lo, hi = plan_shards(int(manifest["total_bytes"]), 3,
+                         manifest_boundaries(manifest)).span_of(1)
+    assert 0 < lo < hi < int(manifest["total_bytes"])
+    [srv] = _serve(d, 23, (64 * MB,))
+    try:
+        half, rep = restore_checkpoint(
+            str(tmp_path), state, step=23,
+            replicas=[Replica("127.0.0.1", srv.port, "/ckpt")],
+            shard_plan=(1, 3))
+    finally:
+        srv.stop()
+    held = [(x, y) for x, y in zip(
+        jax.tree.leaves(half, is_leaf=lambda x: x is None),
+        jax.tree.leaves(state)) if x is not None]
+    assert held
+    assert sum(np.asarray(y).nbytes for _, y in held) == hi - lo
+    for x, y in held:
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_uncommitted_range_keeps_its_leaf_unmaterialized(tmp_path):
+    """Untouched pages read as zeros, never data: a leaf whose range was
+    never committed stays unmaterialized, ``finish()`` refuses the
+    restore, and the sharded contract returns it as None."""
+    from repro.checkpoint.manager import _DATA, _MANIFEST, _StreamingRestore
+
+    state = {"a": jnp.arange(1000, dtype=jnp.float32),
+             "b": jnp.full((64, 64), 3, jnp.int32),
+             "c": jnp.float32(2.5)}
+    d = save_checkpoint(str(tmp_path), 25, state)
+    manifest = json.load(open(os.path.join(d, _MANIFEST)))
+    blob = open(os.path.join(d, _DATA), "rb").read()
+    stream = _StreamingRestore(manifest, state)
+    skip = next(e for e in manifest["leaves"] if e["key"] == "b")
+    lo, hi = skip["offset"], skip["offset"] + skip["nbytes"]
+    stream.sink(0, blob[:lo])
+    stream.sink(hi, blob[hi:])
+    with pytest.raises(IOError, match="restore incomplete"):
+        stream.finish()
+    partial = stream.finish(require_all=False)
+    assert partial["b"] is None
+    for k in ("a", "c"):
+        assert np.array_equal(np.asarray(partial[k]), np.asarray(state[k]))
+    stream.close()
+
+
 # -------------------------------------------- chip_smoke.py, rehearsed on CPU
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))

@@ -102,6 +102,42 @@ def test_importing_obs_loads_no_jax():
     assert out.stdout.strip() == "False"
 
 
+def test_a_restore_records_its_landing_buffer(tmp_path):
+    """``mdtp.buffer`` names the landing buffer's set-up, between the
+    manifest and the first wave, with the blob's size."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.checkpoint import restore_checkpoint, save_checkpoint
+    from repro.transfer import RangeServer, Replica, Throttle
+
+    state = {"w": jax.random.normal(jax.random.PRNGKey(0), (512, 512)),
+             "v": jnp.arange(2048, dtype=jnp.float32)}
+    d = save_checkpoint(str(tmp_path), 5, state)
+    total = os.path.getsize(os.path.join(d, "data.bin"))
+    srv = RangeServer(throttle=Throttle(bytes_per_s=40 << 20)).start()
+    for name in ("manifest.json", "data.bin"):
+        srv.add_file(f"/ckpt/step_0000000005/{name}", os.path.join(d, name))
+    try:
+        with obs.recording() as rec:
+            restore_checkpoint(str(tmp_path), state, step=5,
+                               replicas=[Replica("127.0.0.1", srv.port,
+                                                 "/ckpt")],
+                               wave_bytes=total // 4 + 1)
+    finally:
+        srv.stop()
+    [restore] = rec.find("mdtp.restore")
+    assert [c.name for c in restore.children][:3] == \
+        ["mdtp.manifest", "mdtp.buffer", "mdtp.wave"]
+    [buf] = restore.find("mdtp.buffer")
+    assert buf.attrs == {"bytes": total}
+    waves = restore.find("mdtp.wave")
+    assert len(waves) == 4
+    assert sum(w.attrs["bytes"] for w in waves) == total
+
+
 def test_an_active_profiler_trace_holds_the_spans_on_the_host_plane(
         tmp_path):
     """The pattern of the chip benchmark's CPU trace test: record a trace
