@@ -289,8 +289,10 @@ class _StreamingRestore:
             # never hand XLA a view of the spool mmap we intend to unmap.
             arr = arr.copy()
         shd = self._shards[j]
+        where = ({} if obs.current() is None
+                 else obs.placement(shd, arr.shape, arr.itemsize))
         with obs.span("mdtp.device_put", leaf=e["key"],
-                      bytes=int(e["nbytes"])):
+                      bytes=int(e["nbytes"]), **where):
             self._out[self._slot_of[j]] = (
                 jax.device_put(arr, shd) if shd is not None
                 else jax.device_put(arr))

@@ -34,6 +34,7 @@ range.
 from __future__ import annotations
 
 import contextlib
+import math
 import sys
 import threading
 import time
@@ -41,7 +42,7 @@ from dataclasses import dataclass, field
 from typing import Any, Iterator, Optional
 
 __all__ = ["Span", "ReplicaStats", "Fetch", "Record", "recording", "span",
-           "current"]
+           "current", "placement"]
 
 #: the record being filled, None while recording is off
 _record: Optional["Record"] = None
@@ -240,3 +241,29 @@ def span(name: str, **attrs):
     if rec is None:
         return _NO_SPAN
     return Span(name, attrs, _record=rec)
+
+
+def placement(sharding, shape: tuple, itemsize: int) -> dict:
+    """Where a leaf of ``shape`` lands under ``sharding`` (a JAX
+    ``Sharding``; None: whole on one device), as ``mdtp.device_put``'s
+    attributes: ``devices`` it lands on, ``device_bytes`` (the bytes of
+    every device's shard, a replica counted once per device) and
+    ``gathered_bytes`` (those of shards that are no single contiguous run
+    of the leaf's row-major bytes, so must be gathered before the copy)."""
+    if sharding is None:
+        return {"devices": 1, "device_bytes": math.prod(shape) * itemsize,
+                "gathered_bytes": 0}
+    device_bytes = gathered = 0
+    index_map = sharding.devices_indices_map(tuple(shape))
+    for index in index_map.values():
+        extents = [len(range(*s.indices(n))) for s, n in zip(index, shape)]
+        nbytes = math.prod(extents) * itemsize
+        device_bytes += nbytes
+        # row-major contiguous: every dimension after the last partial
+        # one is whole, and every one before it has extent 1
+        partial = [i for i, (e, n) in enumerate(zip(extents, shape))
+                   if e != n]
+        if partial and any(e != 1 for e in extents[:partial[-1]]):
+            gathered += nbytes
+    return {"devices": len(index_map), "device_bytes": device_bytes,
+            "gathered_bytes": gathered}

@@ -180,3 +180,79 @@ def test_an_active_profiler_trace_holds_the_spans_on_the_host_plane(
     for s in rec.find("mdtp.wave") + rec.find("mdtp.restore"):
         _, s0, s1 = found[s.name]
         assert (s1 - s0) / 1e9 == pytest.approx(s.seconds, abs=2e-3)
+
+
+_PLACEMENT = """
+import os, sys, json
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from repro import obs
+from repro.checkpoint import save_checkpoint
+from repro.checkpoint.manager import _StreamingRestore
+mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
+state = {"strided": jnp.arange(64, dtype=jnp.float32).reshape(2, 8, 4),
+         "contiguous": jnp.arange(32, dtype=jnp.float32).reshape(8, 4),
+         "replicated": jnp.arange(16, dtype=jnp.float32)}
+shardings = {"strided": NamedSharding(mesh, P(None, "model")),
+             "contiguous": NamedSharding(mesh, P("model")),
+             "replicated": NamedSharding(mesh, P())}
+d = save_checkpoint(sys.argv[1], 1, state)
+manifest = json.load(open(os.path.join(d, "manifest.json")))
+blob = open(os.path.join(d, "data.bin"), "rb").read()
+inspected = []
+placement = obs.placement
+obs.placement = lambda *a: inspected.append(a) or placement(*a)
+
+def land():
+    stream = _StreamingRestore(manifest, state, shardings)
+    stream.sink(0, blob)
+    return jax.block_until_ready(stream.finish())
+
+land()
+off = len(inspected)
+with obs.recording() as rec:
+    out = land()
+assert all(np.array_equal(out[k], state[k]) for k in state)
+print("PUTS", json.dumps({"inspected_off": off, "spans": {
+    s.attrs["leaf"]: s.attrs for s in rec.find("mdtp.device_put")}}))
+"""
+
+
+def test_device_put_records_where_each_leaf_lands(tmp_path):
+    """On four virtual CPU devices (a subprocess: the device count is
+    fixed when JAX starts): ``devices``, ``device_bytes`` and
+    ``gathered_bytes`` of a strided, a contiguous and a replicated
+    float32 leaf, against hand counts; with recording off no sharding is
+    inspected."""
+    import json
+    import os
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", _PLACEMENT, str(tmp_path)],
+                         capture_output=True, text=True, env=env,
+                         timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    got = json.loads(res.stdout.split("PUTS ", 1)[1])
+    assert got["inspected_off"] == 0
+    # [2, 8, 4] split on axis 1: four [2, 2, 4] shards of 64 bytes, each
+    # two runs of 32 bytes in the blob
+    assert got["spans"]["strided"] == {
+        "leaf": "strided", "bytes": 256, "devices": 4, "device_bytes": 256,
+        "gathered_bytes": 256}
+    # [8, 4] split on axis 0: four [2, 4] shards, each one run of 32 bytes
+    assert got["spans"]["contiguous"] == {
+        "leaf": "contiguous", "bytes": 128, "devices": 4,
+        "device_bytes": 128, "gathered_bytes": 0}
+    # [16] on every device: four whole copies of 64 bytes
+    assert got["spans"]["replicated"] == {
+        "leaf": "replicated", "bytes": 64, "devices": 4, "device_bytes": 256,
+        "gathered_bytes": 0}
+
+
+def test_placement_without_a_sharding_is_one_whole_device():
+    assert obs.placement(None, (3, 5), 2) == {
+        "devices": 1, "device_bytes": 30, "gathered_bytes": 0}
