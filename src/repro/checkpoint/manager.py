@@ -122,6 +122,18 @@ def latest_step(root: str) -> Optional[int]:
     return max(steps) if steps else None
 
 
+def _on_cpu(sharding: Any) -> bool:
+    """Whether ``jax.device_put(x, sharding)`` lands on a CPU device, where
+    it may alias ``x``'s memory instead of copying it."""
+    if sharding is None:
+        sharding = jax.config.jax_default_device or jax.devices()[0]
+    if isinstance(sharding, str):           # a platform name
+        return sharding == "cpu"
+    devices = (sharding.device_set
+               if isinstance(sharding, jax.sharding.Sharding) else {sharding})
+    return any(d.platform == "cpu" for d in devices)
+
+
 class _StreamingRestore:
     """Range sink for ``MDTPClient.fetch``: overlap network with H2D.
 
@@ -134,14 +146,30 @@ class _StreamingRestore:
     first touch, so the receive that fills a page also faults it in.  A
     crash-resumable restore lands in a file-backed spool map instead.
 
+    **Page reuse.**  An in-memory restore that no peer mirror reads
+    treats the map as a pool of pages: a leaf takes a region when it is
+    first written, preferring pages that earlier leaves gave back (right
+    after its blob predecessor's region if that is free, else the
+    best-fitting given-back region), and only when none fits untouched
+    pages at the map's high-water mark.  A leaf gives its region back
+    once its device array ``is_ready()``: the host→device copy has
+    consumed the pages.  So the restore faults in the pages of its live
+    leaves, not the whole blob, and never more than the blob's.  A
+    spool keeps every leaf at its blob offset (the resume journal
+    re-verifies CRCs there), and so does a ``mirrored`` restore (peers
+    are served the blob's bytes from this buffer after the leaves are
+    on the device).
+
     Implements the client's **zero-copy sink protocol**
     (``writable(start, length) -> memoryview`` + ``commit(start,
     nbytes)``): the transfer layer receives socket bytes directly into
-    this sink's blob buffer, so the restore path is
-    copy-free from socket to leaf buffer (the only remaining move is the
-    inherent host→device ``device_put``).  The legacy ``sink(start,
-    data)`` callable is kept (write-then-commit) for callers that hold
-    their own bytes.
+    this sink's landing buffer, so the restore path is copy-free from
+    socket to leaf buffer (the only remaining move is the inherent
+    host→device ``device_put``).  Under page reuse, a range that
+    crosses into a differently placed leaf, or whose bytes are partly
+    landed already, is received into recycled scratch and copied into
+    place at ``commit``.  The legacy ``sink(start, data)`` callable is
+    kept (write-then-commit) for callers that hold their own bytes.
 
     Deliveries may **overlap or repeat**: the sink tracks covered byte
     intervals and only decrements per-leaf countdowns for bytes seen for
@@ -155,9 +183,16 @@ class _StreamingRestore:
 
     def __init__(self, manifest: dict, like: Any,
                  shardings: Optional[Any] = None,
-                 spool_path: Optional[str] = None):
+                 spool_path: Optional[str] = None,
+                 mirrored: bool = False):
         self._covered: list[tuple[int, int]] = []   # disjoint [s, e), sorted
         self.duplicate_bytes = 0                    # re-delivered byte count
+        #: leaf bytes landed in pages no leaf had touched (under page
+        #: reuse, the map's high-water mark), in pages an earlier leaf
+        #: gave back, and bytes copied in from scratch
+        self.fresh_bytes = 0
+        self.reused_bytes = 0
+        self.scratch_bytes = 0
         leaves, self._treedef = _leaf_paths(like)
         by_key = {e["key"]: e for e in manifest["leaves"]}
         shard_leaves = (jax.tree_util.tree_leaves(shardings)
@@ -199,6 +234,7 @@ class _StreamingRestore:
         self._slot_of = []
         self._shards = []
         self._starts = []
+        self._sizes = []
         for i in order:
             e = by_key[leaves[i][0]]
             self._entries.append(e)
@@ -206,6 +242,20 @@ class _StreamingRestore:
             self._slot_of.append(i)
             self._shards.append(shard_leaves[i])
             self._starts.append(int(e["offset"]))
+            self._sizes.append(int(e["nbytes"]))
+        self._reuse = spool_path is None and not mirrored and total > 0
+        #: a PeerMirror serves ``writable(0, total)`` as the blob: not
+        #: once leaves are placed elsewhere and their pages recycled
+        self.mirrorable = not self._reuse
+        n = len(self._entries)
+        self._base: list[Optional[int]] = [None] * n   # region in the map
+        self._pins = [0] * n            # open writable() views per leaf
+        self._retiring: list[int] = []  # on device, region not yet free
+        self._free: list[tuple[int, int]] = []    # given back, [s, e)
+        #: blob start -> (scratch or None, pinned leaves) of each view
+        #: writable() handed out and not yet committed
+        self._open: dict[int, tuple] = {}
+        self._scratch: list[bytearray] = []       # recycled scratch
         # zero-byte leaves (empty arrays) have nothing on the wire
         for j, rem in enumerate(self._remaining):
             if rem == 0:
@@ -230,22 +280,50 @@ class _StreamingRestore:
     def writable(self, start: int, length: int) -> memoryview:
         """Zero-copy destination for ``[start, start + length)``: the
         transfer layer reads socket bytes straight into this view, then
-        calls :meth:`commit` for the bytes that actually landed."""
-        return memoryview(self._buf)[start:start + length]
+        calls :meth:`commit` for the bytes that actually landed.  Under
+        page reuse that is the leaves' regions when no byte of the range
+        has landed yet and its leaves sit in the map as in the blob;
+        otherwise scratch, which :meth:`commit` copies into the leaves
+        still landing (a landed leaf's pages may be a later leaf's by
+        then)."""
+        if not self._reuse:
+            return memoryview(self._buf)[start:start + length]
+        # the client gives a range to one fetch at a time: a range opened
+        # again at a start that is still open follows a fetch that failed
+        self._close(start)
+        leaves = self._unlanded_leaves(start, start + length)
+        if leaves:
+            shift = {self._region(j) - self._starts[j] for j in leaves}
+            if len(shift) == 1:
+                # pinned until closed: a leaf with a view open keeps its
+                # pages even once it is on the device
+                for j in leaves:
+                    self._pins[j] += 1
+                self._open[start] = (None, leaves)
+                lo = start + shift.pop()
+                return memoryview(self._buf)[lo:lo + length]
+        buf = self._take_scratch(length)
+        self._open[start] = (buf, ())
+        return memoryview(buf)[:length]
 
     def sink(self, start: int, data) -> None:
         """Legacy byte-delivery path: copy ``data`` (bytes or a transient
         memoryview) into place, then account for it."""
-        end = start + len(data)
-        if end <= start:
+        n = len(data)
+        if n <= 0:
             return
-        self._buf[start:end] = data
-        self.commit(start, len(data))
+        self.writable(start, n)[:] = data
+        self.commit(start, n)
 
     def commit(self, start: int, nbytes: int) -> None:
         """Account for ``nbytes`` landed at ``start`` (already in the
-        buffer — via :meth:`writable` or :meth:`sink`)."""
+        buffer — via :meth:`writable` or :meth:`sink`; under page reuse,
+        copied in here from the scratch :meth:`writable` handed out)."""
         end = start + nbytes
+        scratch = self._close(start)
+        if scratch is not None:
+            self._copy_in(scratch, start, end)
+            self._scratch.append(scratch)
         if end <= start:
             return
         fresh = self._claim_new(start, end)
@@ -278,17 +356,128 @@ class _StreamingRestore:
             j += 1
         return completed
 
+    def _close(self, start: int) -> Optional[bytearray]:
+        """Close the view :meth:`writable` opened at ``start``, if any:
+        unpin its leaves; return its scratch (None for a direct view)."""
+        scratch, pinned = self._open.pop(start, (None, ()))
+        for j in pinned:
+            self._pins[j] -= 1
+        return scratch
+
+    def _unlanded_leaves(self, start: int, end: int) -> Optional[list[int]]:
+        """The leaves ``[start, end)`` lies in, if every byte of it is in
+        some leaf and none has landed yet; else None."""
+        i = bisect.bisect_left(self._covered, (end,)) - 1
+        if i >= 0 and self._covered[i][1] > start:
+            return None
+        leaves, inside = [], 0
+        j = max(bisect.bisect_right(self._starts, start) - 1, 0)
+        while j < len(self._entries) and self._starts[j] < end:
+            overlap = (min(end, self._starts[j] + self._sizes[j])
+                       - max(start, self._starts[j]))
+            if overlap > 0:
+                leaves.append(j)
+                inside += overlap
+            j += 1
+        return leaves if inside == end - start else None
+
+    def _region(self, j: int) -> int:
+        """Map offset of leaf ``j``'s bytes, placing it on first use."""
+        base = self._base[j]
+        if base is None:
+            base = self._base[j] = self._place(j)
+        return base
+
+    def _place(self, j: int) -> int:
+        n = self._sizes[j]
+        if not self._reuse or not n:
+            self.fresh_bytes += n
+            return self._starts[j]
+        self._give_back()
+        top = self.fresh_bytes
+        base = self._fit(j, n)
+        self.fresh_bytes = max(top, base + n)
+        self.reused_bytes += n - (self.fresh_bytes - top)
+        return base
+
+    def _give_back(self) -> None:
+        """Free the regions of leaves whose device arrays are ready (the
+        host→device copy has read the pages) and that no open view
+        pins.  Never waits: a copy still in flight keeps its pages."""
+        keep = []
+        for j in self._retiring:
+            out = self._out[self._slot_of[j]]
+            if self._pins[j] or out is None or not out.is_ready():
+                keep.append(j)
+                continue
+            base, self._base[j] = self._base[j], None
+            claim_interval(self._free, base, base + self._sizes[j])
+        self._retiring = keep
+
+    def _fit(self, j: int, n: int) -> int:
+        """Where ``n`` bytes of leaf ``j`` go: right after leaf ``j - 1``'s
+        region if that much is free there, so a range crossing the two
+        lands in one view; else the smallest free region that fits; else
+        the free region below the high-water mark, grown past it; else
+        untouched pages at the mark.  Never past the map: the mark only
+        moves by bytes of leaves placed, which sum to the blob."""
+        prev = self._base[j - 1] if j else None
+        if prev is not None:
+            base = prev + self._sizes[j - 1]
+            if any(s <= base and base + n <= e for s, e in self._free):
+                return self._take(base, n)
+        fits = [(e - s, s) for s, e in self._free if e - s >= n]
+        if fits:
+            return self._take(min(fits)[1], n)
+        if self._free and self._free[-1][1] == self.fresh_bytes:
+            return self._free.pop()[0]
+        return self.fresh_bytes
+
+    def _take(self, base: int, n: int) -> int:
+        """Remove ``[base, base + n)`` from the free regions."""
+        i = bisect.bisect_right(self._free, (base, float("inf"))) - 1
+        s, e = self._free[i]
+        self._free[i:i + 1] = [(a, b) for a, b in ((s, base), (base + n, e))
+                               if b > a]
+        return base
+
+    def _take_scratch(self, length: int) -> bytearray:
+        """The smallest recycled scratch buffer of ``length`` bytes or
+        more, else a new one."""
+        fits = [(len(b), i) for i, b in enumerate(self._scratch)
+                if len(b) >= length]
+        return self._scratch.pop(min(fits)[1]) if fits else bytearray(length)
+
+    def _copy_in(self, scratch: bytearray, start: int, end: int) -> None:
+        """Copy blob bytes ``[start, end)`` from ``scratch`` into the
+        regions of their leaves that are still landing.  A landed leaf's
+        pages may belong to a later leaf by now; a landing one's landed
+        bytes are overwritten with the same verified bytes."""
+        src = memoryview(scratch)
+        dst = memoryview(self._buf)
+        j = max(bisect.bisect_right(self._starts, start) - 1, 0)
+        while j < len(self._entries) and self._starts[j] < end:
+            s0 = self._starts[j]
+            lo = max(start, s0)
+            hi = min(end, s0 + self._sizes[j])
+            if hi > lo and self._remaining[j]:
+                base = self._region(j) + lo - s0
+                dst[base:base + hi - lo] = src[lo - start:hi - start]
+                self.scratch_bytes += hi - lo
+            j += 1
+
     def _materialize(self, j: int) -> None:
         e = self._entries[j]
+        shd = self._shards[j]
         arr = np.frombuffer(
             self._buf, dtype=np.dtype(e["dtype"]),
             count=int(np.prod(e["shape"])) if e["shape"] else 1,
-            offset=int(e["offset"])).reshape(e["shape"])
-        if self._mmap is not None:
+            offset=self._region(j)).reshape(e["shape"])
+        if self._mmap is not None or (self._reuse and _on_cpu(shd)):
             # device_put may alias aligned host memory on CPU backends;
-            # never hand XLA a view of the spool mmap we intend to unmap.
+            # never hand XLA a view of the spool mmap we intend to unmap,
+            # nor of pages a later leaf will be given
             arr = arr.copy()
-        shd = self._shards[j]
         where = ({} if obs.current() is None
                  else obs.placement(shd, arr.shape, arr.itemsize))
         with obs.span("mdtp.device_put", leaf=e["key"],
@@ -296,6 +485,8 @@ class _StreamingRestore:
             self._out[self._slot_of[j]] = (
                 jax.device_put(arr, shd) if shd is not None
                 else jax.device_put(arr))
+        if self._reuse and self._sizes[j]:
+            self._retiring.append(j)
 
     def finish(self, require_all: bool = True) -> Any:
         """Assemble the restored pytree.  ``require_all=False`` is the
@@ -360,8 +551,12 @@ def _finish_restore(stream: _StreamingRestore, jr, spool: Optional[str],
     scratch state (journal + spool) once every leaf is safely on device —
     ``device_put`` dispatch is async, so block before unmapping the spool
     the arrays were read from."""
-    with obs.span("mdtp.finish"):
+    with obs.span("mdtp.finish") as span:
         state = stream.finish(require_all)
+        if obs.current() is not None:
+            span.attrs.update(fresh_bytes=stream.fresh_bytes,
+                              reused_bytes=stream.reused_bytes,
+                              scratch_bytes=stream.scratch_bytes)
         if jr is not None:
             jax.block_until_ready(state)
             jr.complete()
@@ -557,7 +752,8 @@ def restore_checkpoint(
                     total_bytes=total, meta={"step": int(step)})
             with obs.span("mdtp.buffer", bytes=total):
                 stream = _StreamingRestore(manifest, like, shardings,
-                                           spool_path=spool)
+                                           spool_path=spool,
+                                           mirrored=mirror is not None)
             if mirror is not None:
                 # peer-assisted broadcast: landed ranges become servable
                 # to other restorers while this restore is in flight

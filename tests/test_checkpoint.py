@@ -1,6 +1,7 @@
 """Checkpoint manager: atomicity, GC, async save, multi-source restore,
 elastic resharding."""
 
+import asyncio
 import json
 import os
 import threading
@@ -483,6 +484,178 @@ def test_uncommitted_range_keeps_its_leaf_unmaterialized(tmp_path):
     for k in ("a", "c"):
         assert np.array_equal(np.asarray(partial[k]), np.asarray(state[k]))
     stream.close()
+
+
+# ----------------------------------------------- page reuse in the landing map
+
+def _four_leaves(n=16384):
+    """Four leaves of ``n`` float32s each (key order a, b, c, d), every
+    one drawn apart so a leaf landed in another's pages shows."""
+    return {k: jax.random.normal(jax.random.PRNGKey(30 + i), (n,))
+            for i, k in enumerate("abcd")}
+
+
+def _stream_of(tmp_path, state, step, **kw):
+    from repro.checkpoint.manager import _DATA, _MANIFEST, _StreamingRestore
+
+    d = save_checkpoint(str(tmp_path), step, state)
+    manifest = json.load(open(os.path.join(d, _MANIFEST)))
+    blob = open(os.path.join(d, _DATA), "rb").read()
+    at = {e["key"]: (e["offset"], e["offset"] + e["nbytes"])
+          for e in manifest["leaves"]}
+    return _StreamingRestore(manifest, state, **kw), blob, at
+
+
+def _land(stream, blob, lo, hi):
+    """Deliver ``[lo, hi)`` as the transfer client does: receive into
+    ``writable``, then ``commit``."""
+    stream.writable(lo, hi - lo)[:] = blob[lo:hi]
+    stream.commit(lo, hi - lo)
+
+
+def test_waved_restore_lands_later_leaves_in_given_back_pages(tmp_path):
+    """Three waves over two mirrors, leaves b and c straddling the wave
+    boundaries: c and d land in the pages a and b gave back once on the
+    device, and every leaf is bit-exact — on the CPU only because the
+    landing copies a leaf before ``device_put`` aliases its pages."""
+    from repro import obs
+
+    state = {k: jax.random.normal(jax.random.PRNGKey(40 + i), (160, 1024))
+             for i, k in enumerate("abcd")}
+    d = save_checkpoint(str(tmp_path), 31, state)
+    total = os.path.getsize(os.path.join(d, "data.bin"))
+    servers = _serve(d, 31, (30 * MB, 60 * MB))
+    try:
+        replicas = [Replica("127.0.0.1", s.port, "/ckpt") for s in servers]
+        with obs.recording() as rec:
+            restored, _ = restore_checkpoint(
+                str(tmp_path), state, step=31, replicas=replicas,
+                wave_bytes=total // 3 + 1)
+            restored = jax.block_until_ready(restored)
+    finally:
+        for s in servers:
+            s.stop()
+    assert _trees_equal(state, restored)
+    [fin] = rec.find("mdtp.finish")
+    assert fin.attrs["fresh_bytes"] < total
+    assert fin.attrs["reused_bytes"] > 0
+    assert fin.attrs["fresh_bytes"] + fin.attrs["reused_bytes"] == total
+
+
+def test_late_delivery_of_a_retired_leaf_spares_its_pages_new_owner(
+        tmp_path):
+    """A view of a's range handed out before a landed pins a's pages
+    until its commit; once free they go to c, and late or duplicate
+    deliveries of a's range, written with garbage, never reach c."""
+    state = _four_leaves()
+    stream, blob, at = _stream_of(tmp_path, state, 32)
+    (a0, a1), (b0, b1), (c0, c1) = at["a"], at["b"], at["c"]
+    early = stream.writable(a0 + 4096, 4096)   # open before a lands
+    _land(stream, blob, a0, a1)                # a on the device
+    _land(stream, blob, b0, b1)                # a pinned: b takes fresh pages
+    assert stream.reused_bytes == 0
+    early[:] = b"\xff" * 4096
+    stream.commit(a0 + 4096, 4096)             # a duplicate; unpins a
+    _land(stream, blob, c0, c0 + 4096)         # c gets a's and b's pages
+    assert stream.reused_bytes == c1 - c0
+    stream.writable(a0, 8192)[:] = b"\xee" * 8192
+    stream.commit(a0, 8192)
+    stream.sink(b0 - 100, b"\xdd" * 200)
+    _land(stream, blob, c0 + 4096, c1)
+    _land(stream, blob, *at["d"])
+    assert _trees_equal(state, jax.block_until_ready(stream.finish()))
+    assert stream.duplicate_bytes == 4096 + 8192 + 200
+
+
+def test_range_crossing_differently_placed_leaves_lands_through_scratch(
+        tmp_path):
+    """c takes a's given-back pages, b its own slot: a range across the
+    b/c boundary goes through scratch and lands bit-exact in both."""
+    state = _four_leaves()
+    stream, blob, at = _stream_of(tmp_path, state, 33)
+    (b0, b1), (c0, c1) = at["b"], at["c"]
+    _land(stream, blob, *at["a"])
+    _land(stream, blob, c0 + 4096, c0 + 8192)  # c in a's pages
+    assert stream.reused_bytes == c1 - c0
+    view = stream.writable(b1 - 4096, 8192)    # b lands at its own slot
+    assert view.obj is not stream._buf
+    view[:] = blob[b1 - 4096:b1 + 4096]
+    stream.commit(b1 - 4096, 8192)
+    assert stream.scratch_bytes == 8192
+    _land(stream, blob, b0, b1 - 4096)
+    _land(stream, blob, c0 + 8192, c1)
+    _land(stream, blob, *at["d"])
+    assert _trees_equal(state, jax.block_until_ready(stream.finish()))
+    assert stream.fresh_bytes + stream.reused_bytes == len(blob)
+
+
+@pytest.mark.parametrize("keep", ["resume", "mirror"])
+def test_spool_and_mirrored_restores_keep_blob_offsets(tmp_path, keep):
+    """A spool (its journal re-verifies CRCs at blob offsets) and a
+    restore a peer mirror serves keep every leaf at its blob offset: no
+    page reused, and the mirror serves the blob's bytes after the leaves
+    are on the device."""
+    from repro import obs
+    from repro.transfer import MDTPClient, PeerMirror
+
+    state = _four_leaves(65536)
+    d = save_checkpoint(str(tmp_path), 34, state)
+    blob = open(os.path.join(d, "data.bin"), "rb").read()
+    servers = _serve(d, 34, (64 * MB,))
+    mirror = PeerMirror() if keep == "mirror" else None
+    kw = ({"mirror": mirror} if mirror is not None
+          else {"resume": str(tmp_path / "scratch")})
+    try:
+        with obs.recording() as rec:
+            restored, _ = restore_checkpoint(
+                str(tmp_path), state, step=34,
+                replicas=[Replica("127.0.0.1", servers[0].port, "/ckpt")],
+                wave_bytes=len(blob) // 4 + 1, **kw)
+            restored = jax.block_until_ready(restored)
+        assert _trees_equal(state, restored)
+        if mirror is not None:
+            served, _ = asyncio.run(
+                MDTPClient([mirror.replica]).fetch(len(blob)))
+            assert bytes(served) == blob
+    finally:
+        servers[0].stop()
+        if mirror is not None:
+            mirror.stop()
+    [fin] = rec.find("mdtp.finish")
+    assert fin.attrs["reused_bytes"] == 0
+    assert fin.attrs["scratch_bytes"] == 0
+    assert fin.attrs["fresh_bytes"] == len(blob)
+
+
+def test_mirrored_stream_still_mounts_and_a_reusing_one_refuses(tmp_path):
+    """``PeerMirror.bind`` mounts ``writable(0, total)`` as the blob: a
+    stream that recycles pages says it cannot back a mirror."""
+    from repro.transfer import PeerMirror
+
+    state = _four_leaves(1024)
+    stream, _, _ = _stream_of(tmp_path, state, 35)
+    with pytest.raises(ValueError, match="cannot back a mirror"):
+        PeerMirror(stream)
+    mirrored, _, _ = _stream_of(tmp_path, state, 36, mirrored=True)
+    PeerMirror(mirrored).stop()
+
+
+def test_pages_of_a_leaf_whose_copy_is_in_flight_are_not_reused(
+        tmp_path, monkeypatch):
+    """While a's device array is not ready its pages stay a's: c takes
+    fresh pages.  Once ready, the next leaf placed takes them."""
+    state = _four_leaves()
+    stream, blob, at = _stream_of(tmp_path, state, 37)
+    _land(stream, blob, *at["a"])
+    array_type = type(stream._out[0])
+    monkeypatch.setattr(array_type, "is_ready", lambda self: False)
+    _land(stream, blob, *at["c"])
+    assert stream.reused_bytes == 0
+    monkeypatch.undo()
+    _land(stream, blob, *at["b"])
+    assert stream.reused_bytes == at["b"][1] - at["b"][0]
+    _land(stream, blob, *at["d"])
+    assert _trees_equal(state, jax.block_until_ready(stream.finish()))
 
 
 # -------------------------------------------- chip_smoke.py, rehearsed on CPU

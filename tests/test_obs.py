@@ -138,6 +138,44 @@ def test_a_restore_records_its_landing_buffer(tmp_path):
     assert sum(w.attrs["bytes"] for w in waves) == total
 
 
+@pytest.mark.parametrize("landing", ["memory", "resume"])
+def test_a_restore_records_its_landing_pages(tmp_path, landing):
+    """``mdtp.finish`` carries how the leaves landed: in fresh pages, in
+    pages earlier leaves gave back (none for a spool, which keeps blob
+    offsets), and through scratch; fresh and reused add up to the
+    blob."""
+    import os
+
+    import jax
+
+    from repro.checkpoint import restore_checkpoint, save_checkpoint
+    from repro.transfer import RangeServer, Replica, Throttle
+
+    state = {k: jax.random.normal(jax.random.PRNGKey(i), (128, 1024))
+             for i, k in enumerate("abcd")}
+    d = save_checkpoint(str(tmp_path), 6, state)
+    total = os.path.getsize(os.path.join(d, "data.bin"))
+    srv = RangeServer(throttle=Throttle(bytes_per_s=40 << 20)).start()
+    for name in ("manifest.json", "data.bin"):
+        srv.add_file(f"/ckpt/step_0000000006/{name}", os.path.join(d, name))
+    kw = {"resume": str(tmp_path / "scratch")} if landing == "resume" else {}
+    try:
+        with obs.recording() as rec:
+            restore_checkpoint(str(tmp_path), state, step=6,
+                               replicas=[Replica("127.0.0.1", srv.port,
+                                                 "/ckpt")],
+                               wave_bytes=total // 4 + 1, **kw)
+    finally:
+        srv.stop()
+    [fin] = rec.find("mdtp.finish")
+    assert set(fin.attrs) == {"fresh_bytes", "reused_bytes", "scratch_bytes"}
+    assert fin.attrs["fresh_bytes"] + fin.attrs["reused_bytes"] == total
+    assert 0 <= fin.attrs["scratch_bytes"] <= total
+    if landing == "resume":
+        assert fin.attrs["reused_bytes"] == 0
+        assert fin.attrs["scratch_bytes"] == 0
+
+
 def test_an_active_profiler_trace_holds_the_spans_on_the_host_plane(
         tmp_path):
     """The pattern of the chip benchmark's CPU trace test: record a trace
