@@ -35,31 +35,10 @@ POLICIES = {
     "bittorrent": BitTorrentPolicy,
 }
 
-#: every emit() lands here too, so drivers can serialize a whole run
-#: (``benchmarks.run --json`` → BENCH_autotune.json).
-_ROWS: list[dict] = []
-
 
 def emit(name: str, us_per_call: float, derived, *extra) -> None:
     cols = [name, f"{us_per_call:.1f}", str(derived)] + [str(e) for e in extra]
-    _ROWS.append({
-        "name": name,
-        "us_per_call": float(us_per_call),
-        "derived": str(derived),
-        "extra": [str(e) for e in extra],
-    })
     print(",".join(cols), flush=True)
-
-
-def emitted_rows() -> list[dict]:
-    """All rows emitted so far in this process (insertion order)."""
-    return list(_ROWS)
-
-
-def reset_rows() -> None:
-    """Drop accumulated rows (drivers call this at run start so a second
-    in-process run can't leak stale rows into its --json artifact)."""
-    _ROWS.clear()
 
 
 def run_cells(name, policy_name, servers, file_size, reps: int, policy_kwargs=None):

@@ -160,7 +160,7 @@ class _StreamingRestore:
     are served the blob's bytes from this buffer after the leaves are
     on the device).
 
-    Implements the client's **zero-copy sink protocol**
+    Implements the client's **sink protocol**
     (``writable(start, length) -> memoryview`` + ``commit(start,
     nbytes)``): the transfer layer receives socket bytes directly into
     this sink's landing buffer, so the restore path is copy-free from
@@ -168,8 +168,7 @@ class _StreamingRestore:
     host→device ``device_put``).  Under page reuse, a range that
     crosses into a differently placed leaf, or whose bytes are partly
     landed already, is received into recycled scratch and copied into
-    place at ``commit``.  The legacy ``sink(start, data)`` callable is
-    kept (write-then-commit) for callers that hold their own bytes.
+    place at ``commit``.
 
     Deliveries may **overlap or repeat**: the sink tracks covered byte
     intervals and only decrements per-leaf countdowns for bytes seen for
@@ -306,19 +305,10 @@ class _StreamingRestore:
         self._open[start] = (buf, ())
         return memoryview(buf)[:length]
 
-    def sink(self, start: int, data) -> None:
-        """Legacy byte-delivery path: copy ``data`` (bytes or a transient
-        memoryview) into place, then account for it."""
-        n = len(data)
-        if n <= 0:
-            return
-        self.writable(start, n)[:] = data
-        self.commit(start, n)
-
     def commit(self, start: int, nbytes: int) -> None:
         """Account for ``nbytes`` landed at ``start`` (already in the
-        buffer — via :meth:`writable` or :meth:`sink`; under page reuse,
-        copied in here from the scratch :meth:`writable` handed out)."""
+        buffer via :meth:`writable`; under page reuse, copied in here
+        from the scratch :meth:`writable` handed out)."""
         end = start + nbytes
         scratch = self._close(start)
         if scratch is not None:

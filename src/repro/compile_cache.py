@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache, kept at one fixed path.
 
 Entry points call :func:`enable_compile_cache` once at start-up
-(``chip_smoke.py``, ``benchmarks/run.py``); importing ``repro`` never does.
+(``chip_smoke.py``, the chip benchmark's harness); importing ``repro``
+never does.
 The cache directory is part of what a hit is keyed on, so it never moves:
 ``JAX_COMPILATION_CACHE_DIR`` where that is set (JAX reads it itself),
 otherwise ``<repo root>/.jax_cache``.

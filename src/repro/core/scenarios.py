@@ -121,8 +121,7 @@ def with_added_latency(
 # (§IV).  A managed fleet packs K concurrent transfers into the same bins;
 # the simulator-side mirror models contention as a fair k-way bandwidth
 # split per replica (TCP-fair sharing of each mirror's uplink), which is
-# what ``repro.core.autotune.contention_sweep`` vmaps over and what
-# ``benchmarks/contention_bench.py`` replays phase by phase.
+# what ``repro.core.autotune.contention_sweep`` vmaps over.
 
 
 def shared_bottleneck(rtt: float = _DEFAULT_RTT,
@@ -265,8 +264,7 @@ def flash_crowd_traces(rtt: float = _DEFAULT_RTT) -> list[FlashCrowdTrace]:
       probation + admission are jointly built for.
 
     Deterministic fleets (``jitter=0``) and arrival grids, so real-socket
-    replays (``benchmarks/flashcrowd_bench.py``) and simulator runs see
-    the identical storm.
+    replays and simulator runs see the identical storm.
     """
     base = tuple(paper_baseline(rtt=rtt, jitter=0.0))
     fastest = max(range(len(base)), key=lambda i: base[i].bandwidth)
@@ -474,9 +472,9 @@ def swarm_traces(rtt: float = _DEFAULT_RTT) -> list[SwarmTrace]:
 
     * ``pair`` — 2 restorers: the minimal swarm (one peer each); mostly
       a sanity anchor, peer capacity equals origin capacity.
-    * ``quad`` — 4 restorers arriving together, early peer onset: the
-      real-socket benchmark's shape (``benchmarks/broadcast_bench.py``
-      runs this with actual ``PeerMirror`` fleets).
+    * ``quad`` — 4 restorers arriving together, early peer onset (the
+      shape ``tests/test_broadcast.py`` runs with actual ``PeerMirror``
+      fleets).
     * ``cold-start`` — 8 restorers behind a LATE onset: the origin-bound
       opening phase dominates, the regime where striped first-fetches
       (de-correlating what each node asks the origin for) matter most.
@@ -547,11 +545,10 @@ class ShardTrace:
 
 
 def shard_traces(rtt: float = _DEFAULT_RTT) -> list[ShardTrace]:
-    """The two regimes ``benchmarks/shard_bench.py`` mirrors with real
-    sockets:
+    """The two sharded-restore regimes:
 
     * ``balanced`` — 4 hosts, no straggler: stealing should find nothing
-      to do and cost nothing (the win-guard's "do no harm" side).
+      to do and cost nothing (the "do no harm" side).
     * ``straggler`` — 4 hosts, one origin at 1/8 rate: the regime where
       work stealing converts the victim's makespan from span/slow-rate
       toward span/(slow + thieves' fair shares).

@@ -208,9 +208,8 @@ class _VirtualRangeClient(MDTPClient):
         outer = self
 
         class _VConn(_Conn):
-            async def fetch_range(conn_self, start, end, into=None,
+            async def fetch_range(conn_self, start, end, into,
                                   progress=None):
-                parts = []
                 nbytes, elapsed, rtt_inc = 0, 0.0, False
                 header_wait = body_read = 0.0
                 if progress is not None and len(progress) > 1:
@@ -224,13 +223,10 @@ class _VirtualRangeClient(MDTPClient):
                     real_start = outer._ranges[row][0] + row_off
                     take = min(end - pos + 1,
                                int(outer._starts[row + 1] - pos))
-                    sub = (into[nbytes:nbytes + take]
-                           if into is not None else None)
                     reply = await _Conn.fetch_range(
                         conn_self, int(real_start),
-                        int(real_start + take - 1), into=sub)
-                    if into is None:
-                        parts.append(reply.data)
+                        int(real_start + take - 1),
+                        into=into[nbytes:nbytes + take])
                     nbytes += reply.nbytes
                     if progress is not None:
                         # piece-grained: good enough for the hedging
@@ -244,10 +240,8 @@ class _VirtualRangeClient(MDTPClient):
                         break   # short piece: stop — later pieces would
                         # land at the wrong virtual offsets
                     pos += take
-                data = (into[:nbytes] if into is not None
-                        else b"".join(parts))
-                return _RangeReply(data, nbytes, elapsed, rtt_inc,
+                return _RangeReply(into[:nbytes], nbytes, elapsed, rtt_inc,
                                    header_wait=header_wait,
                                    body_read=body_read)
 
-        return _VConn(replica, request_latency=self.request_latency)
+        return _VConn(replica)

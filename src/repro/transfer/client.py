@@ -34,16 +34,21 @@ asyncio's ``loop.sock_*`` primitives with:
 
 Sink contract
 -------------
-``fetch(size, sink=...)`` accepts either:
+Every fetch lands through one :class:`~repro.transfer.sink.Sink`:
+``writable(start, length) -> memoryview`` (the client reads the socket
+directly into the returned view) and ``commit(start, nbytes)`` once the
+bytes landed.  ``fetch(size, sink=...)`` accepts:
 
-* a callable ``sink(start, view)`` — ``view`` is a ``memoryview`` that is
-  only valid DURING the call (the backing buffer is per-chunk scratch);
-  a sink that wants to keep the bytes must copy before returning, or
-* an object with ``writable(start, length) -> memoryview`` and
-  ``commit(start, nbytes)`` — the client reads the socket directly into
-  the returned view and calls ``commit`` once the bytes landed, so the
-  path from socket to the sink's buffer is copy-free
-  (``repro.checkpoint.manager._StreamingRestore`` implements this).
+* nothing — the fetch lands in its own in-memory
+  :class:`~repro.transfer.sink.BufferSink` (over ``into`` if given) and
+  returns that buffer,
+* an object with ``writable`` and ``commit``, used as it is
+  (``repro.checkpoint.manager._StreamingRestore`` is one), or
+* a callable ``sink(start, view)``, wrapped in
+  :class:`~repro.transfer.sink.CallableSink` — ``view`` is a
+  ``memoryview`` that is only valid DURING the call (the backing buffer
+  is per-range scratch); a sink that wants to keep the bytes must copy
+  before returning.
 
 The client is transport-generic: anything exposing ``fetch_range`` works
 (tests use the in-process ``RangeServer``; production would point at real
@@ -65,6 +70,7 @@ from repro.core.chunking import ChunkParams, default_chunk_params
 from repro.core.throughput import make_estimator, rtt_corrected_bandwidth
 from repro.transfer.journal import merge_intervals
 from repro.transfer.sched import ChunkScheduler, defaults as sched_defaults
+from repro.transfer.sink import BufferSink, CallableSink
 # _Conn/_RangeReply re-exported here: the data pipeline and the fleet
 # manager import them from this module (their historical home)
 from repro.transfer.transport import (_Conn, _RangeReply, _crc32_async,
@@ -78,9 +84,9 @@ __all__ = ["Replica", "ClientOptions", "TransferReport", "MDTPClient",
 #: the wire while the previous body streams (the RTT-hiding that matters)
 #: at minimal client-side concurrency — important because lane tasks
 #: share one event loop and a loaded host inflates their scheduling
-#: delays, which distorts throughput observations.  High-RTT paths gain
-#: another ~10-20% from depth 4 (see benchmarks/dataplane_bench.py);
-#: tune per deployment via ``MDTPClient(pipeline_depth=...)``.
+#: delays, which distorts throughput observations.  High-RTT paths may
+#: gain from a deeper pipe; tune per deployment via
+#: ``MDTPClient(pipeline_depth=...)``.
 DEFAULT_PIPELINE_DEPTH = sched_defaults.PIPELINE_DEPTH
 
 #: endgame re-poll cadence (s) for lanes parked with hedging enabled: a
@@ -146,10 +152,9 @@ class Replica:
 
 @dataclass(frozen=True)
 class ClientOptions:
-    """Consolidated :class:`MDTPClient` configuration.
+    """Consolidated :class:`MDTPClient` configuration, grouped by concern.
 
-    What used to be 15 bare constructor kwargs, grouped by concern.  The
-    bare kwargs still work (``MDTPClient(reps, pipeline_depth=3)`` —
+    The bare kwargs still work (``MDTPClient(reps, pipeline_depth=3)`` —
     they are folded into an options instance, overriding it field by
     field), so existing call sites don't change; new code should prefer
     ``MDTPClient(reps, options=ClientOptions(...))``.
@@ -166,21 +171,10 @@ class ClientOptions:
     #: ``fetch`` unless overridden per call.
     tuner: object = None
 
-    # -- pipeline / zero-copy data plane ----------------------------------
+    # -- pipeline -----------------------------------------------------------
     #: concurrent pipelined requests per replica connection (>= 1;
     #: 1 = the serial request-response data plane).
     pipeline_depth: int = DEFAULT_PIPELINE_DEPTH
-    #: False = legacy copy path (bodies materialize as ``bytes`` and are
-    #: copied into place) — kept as the benchmark baseline and an escape
-    #: hatch; the default receives into the destination buffer.
-    zero_copy: bool = True
-    #: emulated request-path delay per request (see ``_Conn``).
-    request_latency: float = 0.0
-    #: False = legacy half-duplex connections (request writes serialize
-    #: inline behind the write lock instead of draining through the
-    #: independent writer coroutine) — kept as the benchmark baseline
-    #: the duplex win-guard measures against.
-    duplex: bool = True
 
     # -- integrity / retry / timeout --------------------------------------
     #: verify each range's CRC32 against the server's
@@ -317,10 +311,10 @@ class MDTPClient:
     ):
         """``options`` is the consolidated configuration
         (:class:`ClientOptions`, grouped and documented there); any bare
-        keyword from the historical 15-kwarg constructor is still
-        accepted and overrides the corresponding options field — the
-        compatibility shim that keeps every existing call site (and the
-        fleet manager's ``**client_kw`` forwarding) working unchanged.
+        keyword naming one of its fields is also accepted and overrides
+        the corresponding options field — the compatibility shim that
+        keeps every existing call site (and the fleet manager's
+        ``**client_kw`` forwarding) working unchanged.
         An unknown keyword raises ``TypeError`` exactly as before."""
         if options is None:
             try:
@@ -341,9 +335,6 @@ class MDTPClient:
         self.max_failures = options.max_failures
         self.tuner = options.tuner
         self.pipeline_depth = max(int(options.pipeline_depth), 1)
-        self.zero_copy = options.zero_copy
-        self.request_latency = options.request_latency
-        self.duplex = options.duplex
         self.verify_integrity = options.verify_integrity
         self.read_timeout = options.read_timeout
         self.retry_backoff_cap = options.retry_backoff_cap
@@ -355,10 +346,10 @@ class MDTPClient:
         #: case, where the EWMA goes stale).  First completion wins; the
         #: loser is cancelled/discarded with byte accounting on the
         #: report (``hedges_issued`` / ``hedges_won`` /
-        #: ``hedge_wasted_bytes``).  Applies only when assembling
-        #: in-memory (``sink=None``): hedge bodies land in private
-        #: scratch, never the destination, so a losing or corrupt copy
-        #: cannot touch committed bytes.
+        #: ``hedge_wasted_bytes``).  Applies only when the fetch lands in
+        #: its own in-memory buffer (``sink=None``): hedge bodies land in
+        #: private scratch, never the destination, so a losing or
+        #: corrupt copy cannot touch committed bytes.
         self.hedge_quantile = float(options.hedge_quantile)
         #: hard cap on hedge waste as a fraction of the transfer size: a
         #: hedge is only issued while committed waste plus every
@@ -452,8 +443,7 @@ class MDTPClient:
         """Connection factory — subclasses may translate offsets (the data
         pipeline's virtual-blob client) or wrap requests (the fleet
         manager's capped, telemetry-fed connections)."""
-        return _Conn(replica, request_latency=self.request_latency,
-                     read_timeout=self.read_timeout, duplex=self.duplex)
+        return _Conn(replica, read_timeout=self.read_timeout)
 
     def _allocation_throughputs(self, est_values: list) -> list:
         """Per-replica throughput vector the allocator sizes chunks from.
@@ -486,12 +476,11 @@ class MDTPClient:
                     stripe: Optional[tuple] = None,
                     ) -> tuple[Optional[bytearray], TransferReport]:
         """Fetch ``size`` bytes.  ``sink`` (if given) receives ranges as
-        they land — see the module docstring for the two sink protocols
-        (callable receiving transient memoryviews, or ``writable``/
-        ``commit`` for the copy-free path); otherwise an in-memory buffer
-        is assembled (and received into directly — zero-copy).  ``into``
-        supplies that buffer (``len(into) >= size``) instead of a fresh
-        allocation — resume needs the previous attempt's bytes in place.
+        they land — see the module docstring for the destinations it
+        accepts; otherwise the fetch lands in an in-memory buffer it
+        returns.  ``into`` supplies that buffer (``len(into) >= size``)
+        instead of a fresh allocation — resume needs the previous
+        attempt's bytes in place.
 
         ``offset`` shifts every byte-range request (and the ``sink`` start
         offsets) by a constant — a wave of a larger blob fetches
@@ -551,20 +540,27 @@ class MDTPClient:
         # window is exact — so samples are aggregated until the window
         # holds enough signal, then fed to the estimator as one reading
         obs_win = [[0, 0.0] for _ in range(n)]
-        zero_copy = self.zero_copy
-        if sink is not None and into is not None:
+        # the destination, resolved once to a Sink.  ``buf`` is the
+        # fetch's own in-memory buffer (None for a caller's sink); the
+        # in-memory buffer is 0-based, a caller's sink sees ``offset``
+        # too, so every destination position is ``base + start``
+        buf = None
+        base = offset
+        if sink is None:
+            if into is not None and len(into) < size:
+                raise ValueError(f"into buffer ({len(into)} B) smaller than "
+                                 f"transfer size ({size} B)")
+            buf = into if into is not None else bytearray(size)
+            dest, base = BufferSink(size, into=buf), 0
+        elif into is not None:
             raise TypeError("into= only applies when assembling in-memory "
                             "(sink is None)")
-        if into is not None and len(into) < size:
-            raise ValueError(f"into buffer ({len(into)} B) smaller than "
-                             f"transfer size ({size} B)")
-        buf = (into if into is not None else bytearray(size)) \
-            if sink is None else None
-        sink_writable = getattr(sink, "writable", None)
-        sink_commit = getattr(sink, "commit", None)
-        if (sink_writable is None) != (sink_commit is None):
-            raise TypeError(
-                "zero-copy sinks must provide BOTH writable() and commit()")
+        elif hasattr(sink, "writable") != hasattr(sink, "commit"):
+            raise TypeError("sinks must provide BOTH writable() and commit()")
+        elif hasattr(sink, "writable"):
+            dest = sink
+        else:
+            dest = CallableSink(sink)
 
         verify = self.verify_integrity
         journal = resume
@@ -573,14 +569,14 @@ class MDTPClient:
         # the decision brain: every allocation, hedge, and repool choice
         # lives in the sans-I/O ``ChunkScheduler`` (repro.transfer.sched)
         # — this method is transport glue that drives it under ``lock``
-        # and performs the I/O its results prescribe.  Scratch-buffer
-        # hedges need a readable destination to commit to, so hedging is
-        # in-memory-assembly only (see __init__).
+        # and performs the I/O its results prescribe.  A won hedge heals
+        # over a loser's bytes, which only the fetch's own buffer allows,
+        # so hedging is in-memory only (see __init__).
         sched = ChunkScheduler(
             size, [r.mirror for r in self.replicas],
             params=self._params_arg or default_chunk_params(size),
             depth=depth,
-            hedge_quantile=self.hedge_quantile if sink is None else 0.0,
+            hedge_quantile=self.hedge_quantile if buf is not None else 0.0,
             hedge_waste_frac=self.hedge_waste_frac,
             default_rtt=self.DEFAULT_RTT,
             max_failures=self.max_failures,
@@ -611,34 +607,25 @@ class MDTPClient:
         if journal is not None:
             # Replay: every journaled record inside this window whose
             # bytes still verify is covered; everything else re-fetches.
-            # Verification needs a readable destination — the assembly
-            # buffer or a writable() sink view; callable sinks can't be
-            # read back, so their records are trusted as journaled.
-            def _view_of(abs_start: int, nb: int):
-                if buf is not None:
-                    lo = abs_start - offset
-                    return memoryview(buf)[lo:lo + nb]
-                if sink_writable is not None:
-                    return sink_writable(abs_start, nb)
-                return None
-
+            # A callable sink's scratch is not the landed bytes, so its
+            # records are trusted as journaled.
+            readable = not isinstance(dest, CallableSink)
             verified: list[tuple[int, int]] = []
             with obs.span("mdtp.resume_verify"):
                 for s_abs, nb, rcrc in journal.records():
                     if s_abs < offset or s_abs + nb > offset + size:
                         continue
-                    v = _view_of(s_abs, nb)
-                    if v is not None and rcrc is not None \
-                            and await _crc32_async(v) != rcrc:
+                    s_ = s_abs - offset
+                    if readable and rcrc is not None and await _crc32_async(
+                            dest.writable(base + s_, nb)) != rcrc:
                         continue
-                    verified.append((s_abs - offset, nb))
+                    verified.append((s_, nb))
             covered = merge_intervals(verified)
             resumed_bytes = sched.seed_resume(covered)
-            if sink_commit is not None:
-                # drive the sink's covered-interval accounting so resumed
-                # regions materialize exactly like freshly landed ones
-                for s_, n_ in covered:
-                    sink_commit(offset + s_, n_)
+            # drive the sink's covered-interval accounting so resumed
+            # regions materialize exactly like freshly landed ones
+            for s_, n_ in covered:
+                dest.commit(base + s_, n_)
 
         t0 = time.monotonic()
 
@@ -791,7 +778,7 @@ class MDTPClient:
             try:
                 reply = await conn.fetch_range(
                     offset + start, offset + start + length - 1,
-                    into=memoryview(scratch) if zero_copy else None)
+                    into=memoryview(scratch))
             except (ConnectionError, OSError,
                     asyncio.IncompleteReadError) as e:
                 # broken mid-copy — usually the owner landing first and
@@ -810,7 +797,7 @@ class MDTPClient:
             ndata = reply.nbytes
             for sample in conn.take_rtt_samples():
                 sched.observe_rtt(j, sample)
-            body = scratch[:ndata] if zero_copy else reply.data
+            body = scratch[:ndata]
             crc = await crc32(body) if need_crc else None
             if verify and reply.crc32 is not None and crc != reply.crc32:
                 async with lock:
@@ -830,8 +817,8 @@ class MDTPClient:
                     # hedge wins: commit from scratch; the scheduler
                     # keeps the bytes so a late-landing loser body can
                     # be healed back over
-                    if buf is not None:
-                        buf[start:start + ndata] = body
+                    dest.writable(base + start, ndata)[:] = body
+                    dest.commit(base + start, ndata)
                     o_conn = conn_of.get(res.cancel_owner)
                     if journal is not None:
                         journal.record(offset + start, ndata, crc)
@@ -936,20 +923,11 @@ class MDTPClient:
                     start, length, ban, prog = asn
                     if st is not None:
                         st.replicas[i].assigned()
-                # destination: straight into the assembly buffer / the
-                # sink's own storage (zero-copy), or per-chunk scratch
-                # for callable sinks / the legacy copy path.  A raising
-                # ``writable()`` must reclaim like any other failure —
-                # the range is already counted in flight.
+                # the socket reads straight into the destination's view.
+                # A raising ``writable()`` must reclaim like any other
+                # failure — the range is already counted in flight.
                 try:
-                    if sink is None:
-                        mv = (memoryview(buf)[start:start + length]
-                              if zero_copy else None)
-                    elif sink_writable is not None:
-                        mv = sink_writable(offset + start, length)
-                    else:
-                        mv = (memoryview(bytearray(length))
-                              if zero_copy else None)
+                    mv = dest.writable(base + start, length)
                 except BaseException:
                     await _reclaim(i, start, length, ban, count=False)
                     raise
@@ -1026,13 +1004,7 @@ class MDTPClient:
                         win[0], win[1] = 0, 0.0
                     if hedge_q:
                         sched.observe_latency(i, ndata, elapsed)
-                    if sink is None:
-                        if not zero_copy:
-                            buf[start:start + ndata] = reply.data
-                    elif sink_writable is not None:
-                        sink_commit(offset + start, ndata)
-                    else:
-                        sink(offset + start, reply.data)
+                    dest.commit(base + start, ndata)
                 except BaseException:
                     # e.g. the user-supplied sink raised (disk full): the
                     # bytes were NOT delivered — reclaim the whole range

@@ -569,7 +569,7 @@ class _ManagedConn(_Conn):
         self._tid = tid
         self._mgr = manager
 
-    async def fetch_range(self, start: int, end: int, into=None,
+    async def fetch_range(self, start: int, end: int, into,
                           progress=None):
         length = end - start + 1
         budget = None
@@ -640,7 +640,6 @@ class _ManagedClient(MDTPClient):
     def _make_conn(self, replica: Replica) -> _Conn:
         return _ManagedConn(replica, self._manager.fleet, self._tid,
                             manager=self._manager,
-                            request_latency=self.request_latency,
                             read_timeout=self.read_timeout)
 
     def _allocation_throughputs(self, est_values: list) -> list:
@@ -665,8 +664,8 @@ class TransferJob:
     #: seconds after batch start before this transfer begins (staggered
     #: arrivals).
     start_delay: float = 0.0
-    #: destination (``repro.transfer.Sink`` or legacy callable); None =
-    #: assemble in memory.
+    #: destination (``repro.transfer.Sink`` or a callable, see
+    #: ``MDTPClient.fetch``); None = assemble in memory.
     sink: Optional[Any] = None
     tune_interval_bytes: Optional[int] = None
     #: frontier rotation hint ``(k, n)`` — see ``MDTPClient.fetch``.

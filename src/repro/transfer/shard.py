@@ -32,8 +32,8 @@ of the straggling origin.  The victim needs no new protocol and never
 learns it was robbed; the only shared state is the in-process ledger
 that keeps two thieves from claiming the same range.  Stolen bytes are
 duplicated traffic by construction (thief and victim both hold them) —
-the ledger accounts them as the price paid for the makespan win, and
-``benchmarks/shard_bench.py`` guards that trade.
+the ledger accounts them as the price paid for the makespan win
+(``tests/test_shard.py`` counts them).
 """
 
 from __future__ import annotations

@@ -1,11 +1,6 @@
 """The transfer layer's destination contract, as an explicit protocol.
 
-Historically ``MDTPClient.fetch(sink=...)`` accepted two duck-typed
-shapes — a bare callable ``sink(start, view)`` receiving transient
-memoryviews, and an object with ``writable``/``commit`` for the
-zero-copy path — and consumers (the client, the fleet manager, the
-checkpoint restore) each re-described the contract in prose.  This
-module promotes it to one typed :class:`Sink` protocol:
+Every ``MDTPClient.fetch`` lands its bytes through one :class:`Sink`:
 
 * ``writable(start, length) -> memoryview`` — a view of the
   destination for ``[start, start + length)``; the client reads socket
@@ -23,9 +18,9 @@ All three implementations here share one interval-merge implementation
 and the streaming checkpoint restore, so a mirror's advertisement has a
 single source of truth no matter which sink backs it.
 
-``CallableSink`` adapts the legacy callable shape to the protocol: the
-wrapped callable still receives transient views (copy if you keep
-them), but the adapter buffers each range in scratch so the zero-copy
+``CallableSink`` adapts a bare callable ``sink(start, view)`` to the
+protocol: the wrapped callable receives transient views (copy if you
+keep them), and the adapter buffers each range in scratch so the
 receive path and the coverage accessor work.  Note the scratch is
 per-range and released on commit — a ``CallableSink`` cannot back a
 peer mirror (nothing is retained to serve) and cannot be CRC-verified
@@ -76,11 +71,12 @@ class BufferSink:
     The swarm-restore building block: each restoring node lands its
     blob here and mounts the same object on a ``PeerMirror`` — committed
     bytes are immutable thereafter, so server threads may read them
-    concurrently with the ongoing transfer.
+    concurrently with the ongoing transfer.  ``into`` lands in an
+    existing buffer (``len(into) >= size``) instead of a fresh one.
     """
 
-    def __init__(self, size: int):
-        self._buf = bytearray(size)
+    def __init__(self, size: int, *, into: bytearray | None = None):
+        self._buf = bytearray(size) if into is None else into
         self._covered: list[tuple[int, int]] = []    # disjoint [s, e)
         #: re-delivered byte count (overlapping/duplicate commits)
         self.duplicate_bytes = 0
@@ -114,14 +110,14 @@ class BufferSink:
 
 
 class CallableSink:
-    """Adapt a legacy callable ``sink(start, view)`` to :class:`Sink`.
+    """Adapt a callable ``sink(start, view)`` to :class:`Sink`.
 
     ``writable`` hands the client a per-range scratch buffer; ``commit``
     forwards the landed bytes to the callable as a transient view (valid
-    only during the call, exactly like the legacy direct path) and then
-    releases the scratch.  Coverage is tracked so protocol-typed
-    consumers can introspect progress, but nothing is retained — see the
-    module docstring for what that rules out.
+    only during the call) and then releases the scratch.  Coverage is
+    tracked so protocol-typed consumers can introspect progress, but
+    nothing is retained — see the module docstring for what that rules
+    out.
     """
 
     #: scratch-backed: ``writable(0, total)`` is NOT the landed bytes, so

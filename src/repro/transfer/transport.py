@@ -74,8 +74,7 @@ class _RangeReply(NamedTuple):
     """One completed range request, with the timing metadata the
     observation layer needs to de-bias throughput samples."""
 
-    #: the body: ``memoryview`` of the caller's buffer when ``into`` was
-    #: given, freshly-read ``bytes`` otherwise.
+    #: the body: a ``memoryview`` of the caller's ``into`` buffer.
     data: object
     #: body length actually served (may be < requested on a clamped tail).
     nbytes: int
@@ -107,7 +106,7 @@ class _RangeReply(NamedTuple):
 
 
 class _SendOp:
-    """One queued request write (duplex mode).
+    """One queued request write.
 
     Carries the request bytes, the turnstile predecessor (so the writer
     can judge idle-pipe-ness at the moment the request actually hits the
@@ -134,20 +133,17 @@ class _SendOp:
 class _Conn:
     """One persistent pipelined HTTP/1.1 connection on a raw socket.
 
-    Requests may be issued concurrently by several tasks.  In duplex
-    mode (the default) each request is enqueued to an independent writer
-    coroutine that drains the queue onto the socket — a request write
-    never waits behind an in-flight response body, so the pipe stays at
-    depth even when bodies stream for whole RTTs.  Responses are read
-    strictly in request order via a FIFO turnstile (each request waits
-    on its predecessor's completion event); enqueue order and turnstile
-    order are linked atomically, and the single writer preserves that
-    order on the wire.  With ``duplex=False`` the legacy half-duplex
-    path sends inline under the write lock (kept as a benchmark
-    baseline).  Bodies are received with ``sock_recv_into`` directly
-    into the caller's buffer — the only copied bytes are the
-    header-phase read-ahead (bounded by ``_HEADER_RECV`` per response)
-    and encoded bodies' wire scratch.
+    Requests may be issued concurrently by several tasks.  Each request
+    is enqueued to an independent writer coroutine that drains the queue
+    onto the socket — a request write never waits behind an in-flight
+    response body, so the pipe stays at depth even when bodies stream
+    for whole RTTs.  Responses are read strictly in request order via a
+    FIFO turnstile (each request waits on its predecessor's completion
+    event); enqueue order and turnstile order are linked atomically, and
+    the single writer preserves that order on the wire.  Bodies are
+    received with ``sock_recv_into`` directly into the caller's buffer —
+    the only copied bytes are the header-phase read-ahead (bounded by
+    ``_HEADER_RECV`` per response) and encoded bodies' wire scratch.
 
     Collects per-connection RTT samples: the TCP connect time on session
     establishment, then the request-write → status-line turnaround of
@@ -167,18 +163,11 @@ class _Conn:
     #: the zero-copy path per response.
     _HEADER_RECV = 4096
 
-    def __init__(self, replica, request_latency: float = 0.0,
-                 read_timeout: float = 0.0, duplex: bool = True):
+    def __init__(self, replica, read_timeout: float = 0.0):
         #: the replica this session targets — anything with ``host`` /
         #: ``port`` / ``path`` / ``name`` (duck-typed so this module
         #: doesn't import the client layer).
         self.replica = replica
-        #: emulated request-path propagation delay (seconds) — a test and
-        #: benchmark knob: loopback has no real RTT, so the dataplane
-        #: bench injects one here to reproduce the WAN regime where
-        #: pipelining pays off.  Applied before each request send, off
-        #: the critical path of already-streaming predecessors.
-        self.request_latency = request_latency
         #: per-READ inactivity bound (seconds; 0 disables).  A replica
         #: that stalls without dying would otherwise hang a lane forever
         #: — the timeout converts the stall into a ``ConnectionError`` so
@@ -186,10 +175,6 @@ class _Conn:
         #: per socket read, not per request: a huge range streaming
         #: slowly-but-steadily never trips it.
         self.read_timeout = read_timeout
-        #: False = legacy half-duplex sends (inline under the write
-        #: lock) — the benchmark baseline the duplex win-guard measures
-        #: against.
-        self.duplex = duplex
         self.broken = False
         self._sock: Optional[socket.socket] = None
         self._rbuf = bytearray()
@@ -198,8 +183,8 @@ class _Conn:
         #: completion event of the most recently issued request (the
         #: turnstile tail); None = pipe idle since connect.
         self._tail: Optional[asyncio.Event] = None
-        #: duplex writer state: the request queue and the coroutine
-        #: draining it (both created lazily on the first duplex send).
+        #: writer state: the request queue and the coroutine draining it
+        #: (both created lazily on the first send).
         self._sendq: Optional[asyncio.Queue] = None
         self._writer: Optional[asyncio.Task] = None
 
@@ -256,7 +241,7 @@ class _Conn:
             with contextlib.suppress(OSError):
                 self._sock.shutdown(socket.SHUT_RDWR)
 
-    # -- duplex writer -----------------------------------------------------
+    # -- writer ----------------------------------------------------------
 
     def _fail_queued(self, why: str) -> None:
         """Fail every queued-but-unsent request (sync — callable from
@@ -375,23 +360,16 @@ class _Conn:
             headers[k.strip().lower()] = v.strip()
         return code, headers
 
-    async def _read_body(self, n: int, into: Optional[memoryview],
-                         progress: Optional[list] = None):
-        """Read exactly ``n`` body bytes — into the caller's view when
-        given (zero-copy), into fresh ``bytes`` otherwise.  Slot 0 of
+    async def _read_body(self, n: int, view: memoryview,
+                         progress: Optional[list] = None) -> None:
+        """Read exactly ``n`` body bytes into ``view``.  Slot 0 of
         ``progress`` (a list) is kept updated with the byte count landed
         so far — the hedging layer reads it to avoid duplicating ranges
         whose owner has already received most of the body."""
-        if into is None:
-            scratch = bytearray(n)
-            view = memoryview(scratch)
-        else:
-            if len(into) < n:
-                raise ConnectionError(
-                    f"response body {n} B overruns the {len(into)} B "
-                    f"destination range")
-            scratch = None
-            view = into
+        if len(view) < n:
+            raise ConnectionError(
+                f"response body {n} B overruns the {len(view)} B "
+                f"destination range")
         got = min(len(self._rbuf), n)   # header-phase read-ahead first
         if got:
             view[:got] = self._rbuf[:got]
@@ -415,7 +393,6 @@ class _Conn:
             # the bytes genuinely spent, not the whole range
             e.partial_bytes = got
             raise
-        return bytes(scratch) if scratch is not None else view[:n]
 
     # -- requests ----------------------------------------------------------
 
@@ -436,16 +413,14 @@ class _Conn:
                 return None
         return None
 
-    async def fetch_range(self, start: int, end: int,
-                          into: Optional[memoryview] = None,
+    async def fetch_range(self, start: int, end: int, into: memoryview,
                           progress: Optional[list] = None) -> _RangeReply:
         """GET bytes [start, end] inclusive over the persistent session.
 
         May be called concurrently: the request goes on the wire
         immediately (pipelined behind any in-flight predecessors) and the
-        response is read in FIFO order.  With ``into``, the body is
-        received directly into that view and the reply's ``data`` is
-        ``into[:nbytes]``; without it, fresh ``bytes`` are returned.
+        response is read in FIFO order.  The body is received directly
+        into ``into`` and the reply's ``data`` is ``into[:nbytes]``.
         """
         if self._sock is None:
             # concurrent lanes race to the first request: exactly one may
@@ -458,48 +433,23 @@ class _Conn:
                     except BaseException:
                         self.broken = True
                         raise
-        if self.request_latency > 0.0:
-            await asyncio.sleep(self.request_latency)
         my_done = asyncio.Event()
-        op: Optional[_SendOp] = None
-        if self.duplex:
-            # no awaits between the broken check and the enqueue: the
-            # turnstile link and the queue position are taken atomically,
-            # and the single writer preserves that order on the wire
-            if self.broken or self._sock is None:
-                raise ConnectionError("pipelined connection broken")
-            self._ensure_writer()
-            op = _SendOp(self._request_bytes("GET", start, end),
-                         self._tail, progress)
-            prior = op.prior
-            self._tail = my_done
-            self._sendq.put_nowait(op)
-            pipelined, t_send = False, 0.0       # filled in by the writer
-        else:
-            async with self._wlock:
-                if self.broken or self._sock is None:
-                    raise ConnectionError("pipelined connection broken")
-                prior = self._tail
-                self._tail = my_done
-                pipelined = prior is not None and not prior.is_set()
-                t_send = time.monotonic()
-                if progress is not None and len(progress) > 1:
-                    # wire-send stamp for the hedging layer: a range
-                    # starts aging only once its request is on the wire
-                    progress[1] = t_send
-                try:
-                    await asyncio.get_running_loop().sock_sendall(
-                        self._sock, self._request_bytes("GET", start, end))
-                except BaseException:
-                    self.broken = True
-                    my_done.set()
-                    raise
+        # no awaits between the broken check and the enqueue: the
+        # turnstile link and the queue position are taken atomically,
+        # and the single writer preserves that order on the wire
+        if self.broken or self._sock is None:
+            raise ConnectionError("pipelined connection broken")
+        self._ensure_writer()
+        op = _SendOp(self._request_bytes("GET", start, end),
+                     self._tail, progress)
+        prior = op.prior
+        self._tail = my_done
+        self._sendq.put_nowait(op)
         try:
-            if op is not None:
-                # request on the wire (or the connection died first —
-                # every queued-unsent request fails here exactly once)
-                await op.fut
-                pipelined, t_send = op.pipelined, op.t_send
+            # request on the wire (or the connection died first — every
+            # queued-unsent request fails here exactly once)
+            await op.fut
+            pipelined, t_send = op.pipelined, op.t_send
             if prior is not None:
                 await prior.wait()
             if self.broken:
@@ -519,7 +469,8 @@ class _Conn:
             enc_block = codec.parse_encoding(
                 headers.get("x-range-encoding"))
             if enc_block is None:
-                body = await self._read_body(n, into, progress)
+                await self._read_body(n, into, progress)
+                body = into[:n]
                 t_end = time.monotonic()
                 wire_n = None
                 ndec = n
@@ -532,11 +483,12 @@ class _Conn:
                 # lanes' socket reads in the executor anyway.
                 lo, hi = self._decoded_span(headers, start, end)
                 ndec = hi - lo + 1
-                if into is not None and len(into) < ndec:
+                if len(into) < ndec:
                     raise ConnectionError(
                         f"decoded body {ndec} B overruns the "
                         f"{len(into)} B destination range")
-                wire = await self._read_body(n, None, progress)
+                wire = bytearray(n)
+                await self._read_body(n, memoryview(wire), progress)
                 t_end = time.monotonic()
                 wire_n = n
                 # the socket is past this response: release the read
@@ -545,11 +497,8 @@ class _Conn:
                 # stream stays aligned either way — decode failures
                 # mark the conn broken without desyncing it)
                 my_done.set()
-                if into is not None:
-                    await codec.decode_range_async(wire, lo, hi, out=into)
-                    body = into[:ndec]
-                else:
-                    body = await codec.decode_range_async(wire, lo, hi)
+                await codec.decode_range_async(wire, lo, hi, out=into)
+                body = into[:ndec]
             return _RangeReply(
                 data=body, nbytes=ndec,
                 elapsed=t_end - (t_ready if pipelined else t_send),

@@ -123,6 +123,13 @@ def test_multi_source_restore_survives_mirror_death(tmp_path):
             pass
 
 
+def _land(stream, blob, lo, hi):
+    """Deliver ``[lo, hi)`` as the transfer client does: receive into
+    ``writable``, then ``commit``."""
+    stream.writable(lo, hi - lo)[:] = blob[lo:hi]
+    stream.commit(lo, hi - lo)
+
+
 def test_streaming_restore_materializes_leaves_incrementally(tmp_path):
     """The replica restore path streams: each leaf is device_put the
     moment its byte range completes, out-of-order and split deliveries
@@ -142,10 +149,8 @@ def test_streaming_restore_materializes_leaves_incrementally(tmp_path):
     # deliver in reverse order, split mid-leaf and across leaf boundaries
     n = len(blob)
     cuts = [0, 100, 1000, 2500, n]
-    pieces = [(cuts[i], blob[cuts[i]:cuts[i + 1]])
-              for i in range(len(cuts) - 1)]
-    for start, data in reversed(pieces):
-        stream.sink(start, data)
+    for lo, hi in reversed(list(zip(cuts, cuts[1:]))):
+        _land(stream, blob, lo, hi)
     restored = stream.finish()
     assert _trees_equal(state, restored)
 
@@ -166,13 +171,13 @@ def test_streaming_restore_tolerates_overlapping_duplicates(tmp_path):
 
     stream = _StreamingRestore(manifest, state)
     # exact duplicate of a mid-blob range, delivered twice
-    stream.sink(100, blob[100:1000])
-    stream.sink(100, blob[100:1000])
+    _land(stream, blob, 100, 1000)
+    _land(stream, blob, 100, 1000)
     # partial overlaps on both sides, one spanning a leaf boundary
-    stream.sink(0, blob[0:500])
-    stream.sink(800, blob[800:4020])
+    _land(stream, blob, 0, 500)
+    _land(stream, blob, 800, 4020)
     # duplicate covering everything seen so far plus the tail
-    stream.sink(0, blob)
+    _land(stream, blob, 0, n)
     restored = stream.finish()
     assert _trees_equal(state, restored)
     assert stream.duplicate_bytes > 0
@@ -181,8 +186,8 @@ def test_streaming_restore_tolerates_overlapping_duplicates(tmp_path):
     assert all(r == 0 for r in stream._remaining)
 
     # zero-length and fully-duplicate deliveries after completion are no-ops
-    stream.sink(0, b"")
-    stream.sink(0, blob[0:64])
+    _land(stream, blob, 0, 0)
+    _land(stream, blob, 0, 64)
     assert _trees_equal(state, stream.finish())
 
 
@@ -308,7 +313,7 @@ def test_streaming_restore_respects_shardings(tmp_path):
     manifest = json.load(open(os.path.join(d, _MANIFEST)))
     blob = open(os.path.join(d, _DATA), "rb").read()
     stream = _StreamingRestore(manifest, state, shardings)
-    stream.sink(0, blob)
+    _land(stream, blob, 0, len(blob))
     restored = stream.finish()
     assert _trees_equal(state, restored)
     assert restored["w"].sharding.spec == jax.sharding.PartitionSpec(
@@ -426,7 +431,7 @@ def test_landing_buffer_is_a_map_of_the_blob(tmp_path, landing):
     assert isinstance(stream._buf, mmap.mmap)
     assert len(stream._buf) == len(blob)
     assert (stream._mmap is None) == (landing == "memory")
-    stream.sink(0, blob)
+    _land(stream, blob, 0, len(blob))
     restored = jax.block_until_ready(stream.finish())
     assert _trees_equal(state, restored)
     stream.close()
@@ -475,8 +480,8 @@ def test_uncommitted_range_keeps_its_leaf_unmaterialized(tmp_path):
     stream = _StreamingRestore(manifest, state)
     skip = next(e for e in manifest["leaves"] if e["key"] == "b")
     lo, hi = skip["offset"], skip["offset"] + skip["nbytes"]
-    stream.sink(0, blob[:lo])
-    stream.sink(hi, blob[hi:])
+    _land(stream, blob, 0, lo)
+    _land(stream, blob, hi, len(blob))
     with pytest.raises(IOError, match="restore incomplete"):
         stream.finish()
     partial = stream.finish(require_all=False)
@@ -504,13 +509,6 @@ def _stream_of(tmp_path, state, step, **kw):
     at = {e["key"]: (e["offset"], e["offset"] + e["nbytes"])
           for e in manifest["leaves"]}
     return _StreamingRestore(manifest, state, **kw), blob, at
-
-
-def _land(stream, blob, lo, hi):
-    """Deliver ``[lo, hi)`` as the transfer client does: receive into
-    ``writable``, then ``commit``."""
-    stream.writable(lo, hi - lo)[:] = blob[lo:hi]
-    stream.commit(lo, hi - lo)
 
 
 def test_waved_restore_lands_later_leaves_in_given_back_pages(tmp_path):
@@ -560,7 +558,8 @@ def test_late_delivery_of_a_retired_leaf_spares_its_pages_new_owner(
     assert stream.reused_bytes == c1 - c0
     stream.writable(a0, 8192)[:] = b"\xee" * 8192
     stream.commit(a0, 8192)
-    stream.sink(b0 - 100, b"\xdd" * 200)
+    stream.writable(b0 - 100, 200)[:] = b"\xdd" * 200
+    stream.commit(b0 - 100, 200)
     _land(stream, blob, c0 + 4096, c1)
     _land(stream, blob, *at["d"])
     assert _trees_equal(state, jax.block_until_ready(stream.finish()))

@@ -1,6 +1,6 @@
 """Duplex transport lanes: the independent writer coroutine's send
-pipelining, its failure semantics (per-lane conservation, no deadlock on
-a severed socket), and half-duplex parity."""
+pipelining and its failure semantics (per-lane conservation, no deadlock
+on a severed socket)."""
 
 import asyncio
 import hashlib
@@ -9,8 +9,7 @@ import threading
 import numpy as np
 
 from repro.core.chunking import ChunkParams
-from repro.transfer import (MDTPClient, RangeServer, Replica, Throttle,
-                            fetch_blob)
+from repro.transfer import RangeServer, Replica, Throttle, fetch_blob
 from repro.transfer.transport import _Conn
 
 KB = 1024
@@ -28,6 +27,10 @@ def _sha(b) -> str:
     return hashlib.sha256(b).hexdigest()
 
 
+def _into(n: int) -> memoryview:
+    return memoryview(bytearray(n))
+
+
 def test_duplex_writer_pipelines_while_body_in_flight():
     """Two lanes enqueued together: the writer puts the second request
     on the wire while the first body is still streaming, so the second
@@ -40,8 +43,10 @@ def test_duplex_writer_pipelines_while_body_in_flight():
     async def run():
         conn = _Conn(Replica("127.0.0.1", s.port, "/data"))
         try:
-            lane1 = asyncio.ensure_future(conn.fetch_range(0, MB - 1))
-            lane2 = asyncio.ensure_future(conn.fetch_range(MB, 2 * MB - 1))
+            lane1 = asyncio.ensure_future(
+                conn.fetch_range(0, MB - 1, _into(MB)))
+            lane2 = asyncio.ensure_future(
+                conn.fetch_range(MB, 2 * MB - 1, _into(MB)))
             r1, r2 = await asyncio.gather(lane1, lane2)
             assert bytes(r1.data) == blob[:MB]
             assert bytes(r2.data) == blob[MB:]
@@ -69,7 +74,7 @@ def test_conn_death_fails_every_queued_lane_exactly_once():
         conn = _Conn(Replica("127.0.0.1", s.port, "/data"))
         try:
             lanes = [asyncio.ensure_future(
-                conn.fetch_range(i * MB, (i + 1) * MB - 1))
+                conn.fetch_range(i * MB, (i + 1) * MB - 1, _into(MB)))
                 for i in range(8)]
             await asyncio.sleep(0.2)        # queue fills behind body 1
             s.kill_connections()
@@ -104,7 +109,7 @@ def test_abort_does_not_deadlock_queued_writer():
         conn = _Conn(Replica("127.0.0.1", s.port, "/data"))
         try:
             lanes = [asyncio.ensure_future(
-                conn.fetch_range(i * MB, (i + 1) * MB - 1))
+                conn.fetch_range(i * MB, (i + 1) * MB - 1, _into(MB)))
                 for i in range(4)]
             await asyncio.sleep(0.2)        # body 1 mid-flight, 3 queued
             conn.abort()
@@ -155,26 +160,3 @@ def test_client_repools_duplex_queue_on_mirror_death():
             victim.stop()
         except Exception:
             pass
-
-
-def test_half_duplex_fallback_parity():
-    """``duplex=False`` (the benchmark baseline) still moves bytes
-    correctly through the legacy inline-send path."""
-    blob = _blob(6 * MB, seed=4)
-    servers = []
-    for bw in (30 * MB, 60 * MB):
-        s = RangeServer(
-            throttle=Throttle(bytes_per_s=bw, deterministic=True)).start()
-        s.add_blob("/data", blob)
-        servers.append(s)
-    try:
-        replicas = [Replica("127.0.0.1", s.port, "/data") for s in servers]
-        client = MDTPClient(replicas, duplex=False,
-                            params=ChunkParams(initial_chunk=256 * KB,
-                                               large_chunk=MB))
-        data, report = asyncio.run(client.fetch(len(blob)))
-        assert _sha(data) == _sha(blob)
-        assert sum(report.bytes_per_replica.values()) == len(blob)
-    finally:
-        for s in servers:
-            s.stop()

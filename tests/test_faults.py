@@ -137,6 +137,33 @@ def test_corruption_refetched_from_alternate_mirror(blob):
         good.stop()
 
 
+def test_corruption_costs_only_its_ranges(blob):
+    """Corruption is paid per range, not per transfer: with one of two
+    mirrors corrupting 5% of its bodies (fixed seed) and every range
+    exactly 256 KiB, the mirrors serve the blob once plus one range per
+    re-fetch, and no more."""
+    chunk = 256 * 1024
+    bad = _mirror(blob, faults=FaultPolicy(corrupt_rate=0.05, seed=17))
+    good = _mirror(blob)
+    try:
+        replicas = [Replica("127.0.0.1", bad.port, "/data"),
+                    Replica("127.0.0.1", good.port, "/data")]
+        data, report = fetch_blob(
+            replicas, len(blob),
+            params=ChunkParams(initial_chunk=chunk, large_chunk=chunk,
+                               mode="static"),
+            max_failures=50)
+        assert _sha(data) == _sha(blob)
+        corrupt = sum(report.corrupt_ranges.values())
+        assert corrupt == bad.fault_counts.get("corrupt", 0)
+        assert report.refetched_ranges == corrupt
+        served = bad.served_bytes + good.served_bytes
+        assert served <= len(blob) + report.refetched_ranges * chunk
+    finally:
+        bad.stop()
+        good.stop()
+
+
 def test_truncated_bodies_recovered(blob):
     """Mid-body truncation (connection cut) on one mirror: the short
     range re-pools and the fleet still assembles the exact file."""

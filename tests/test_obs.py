@@ -245,7 +245,8 @@ obs.placement = lambda *a: inspected.append(a) or placement(*a)
 
 def land():
     stream = _StreamingRestore(manifest, state, shardings)
-    stream.sink(0, blob)
+    stream.writable(0, len(blob))[:] = blob
+    stream.commit(0, len(blob))
     return jax.block_until_ready(stream.finish())
 
 land()

@@ -300,6 +300,38 @@ def test_hedge_waste_is_conserved_and_bounded():
     assert report.hedge_wasted_bytes <= cap
 
 
+def test_clean_burst_wastes_no_hedge_byte():
+    """Hedging is free on a clean fleet: a flash crowd of six arrivals
+    through the robust manager (hedging and probation on, admission and
+    a byte budget) over three healthy mirrors at 24, 8 and 8 MiB/s
+    duplicates no byte, re-fetches no range and retries no connection.
+    (Probation is not free here: slow strikes trip on healthy mirrors;
+    ROADMAP.md, "Probation trips on a clean fleet".)"""
+    blob = _blob(3 * MB, seed=29)
+    servers = [_mirror(blob, throttle=Throttle(bytes_per_s=r * MB,
+                                               deterministic=True))
+               for r in (24, 8, 8)]
+    try:
+        replicas = [Replica("127.0.0.1", s.port, "/data") for s in servers]
+        mgr = TransferManager(
+            replicas, params=ChunkParams(initial_chunk=256 * 1024,
+                                         large_chunk=MB),
+            max_active_transfers=3, max_inflight_bytes=16 * MB)
+        results = mgr.run([TransferJob(size=len(blob), start_delay=0.05 * j)
+                           for j in range(6)])
+        for buf, _ in results:
+            assert _sha(buf) == _sha(blob)
+        assert len(mgr.reports) == 6
+        for r in mgr.reports:
+            assert r.hedge_wasted_bytes == 0
+            assert r.refetched_ranges == 0
+            assert sum(r.retries_per_replica.values()) == 0
+        assert sum(s.served_bytes for s in servers) == 6 * len(blob)
+    finally:
+        for s in servers:
+            s.stop()
+
+
 def test_hedging_disabled_reports_zero_witnesses():
     blob = _blob(MB, seed=5)
     servers = [_mirror(blob) for _ in range(2)]

@@ -311,31 +311,47 @@ def test_pipelined_connection_death_repools_every_owed_range(blob):
             pass
 
 
-def test_serial_depth_one_still_works(blob):
-    """pipeline_depth=1 degrades to the serial request-response plane."""
+@pytest.mark.parametrize("depth", [1, None], ids=["depth1", "default"])
+@pytest.mark.parametrize("dest", ["memory", "into", "buffer_sink",
+                                  "callable"])
+def test_every_destination_lands_byte_exact(blob, dest, depth):
+    """Every destination ``fetch`` accepts (its own buffer, ``into=``, a
+    ``BufferSink``, a bare callable) lands the blob byte-exact and
+    commits each byte once, on the serial plane (``pipeline_depth=1``)
+    and the default pipelined one."""
+    import asyncio
+
+    from repro.transfer import BufferSink
+
     s = RangeServer().start()
     s.add_blob("/data", blob)
     try:
-        data, report = fetch_blob(
-            [Replica("127.0.0.1", s.port, "/data")], len(blob),
-            params=ChunkParams(initial_chunk=256 * 1024, large_chunk=MB),
-            pipeline_depth=1)
-        assert bytes(data) == blob
-    finally:
-        s.stop()
+        kw = {} if depth is None else {"pipeline_depth": depth}
+        client = MDTPClient([Replica("127.0.0.1", s.port, "/data")],
+                            params=ChunkParams(256 * 1024, MB), **kw)
+        into = bytearray(len(blob)) if dest == "into" else None
+        got = bytearray(len(blob))
+        times = np.zeros(len(blob), np.int64)
 
+        def callable_sink(start, view):
+            got[start:start + len(view)] = view
+            times[start:start + len(view)] += 1
 
-def test_copy_mode_fallback_matches(blob):
-    """``zero_copy=False`` (the legacy bytes-assembly path, kept as the
-    benchmark baseline) still produces identical bytes."""
-    s = RangeServer().start()
-    s.add_blob("/data", blob)
-    try:
-        data, _ = fetch_blob(
-            [Replica("127.0.0.1", s.port, "/data")], len(blob),
-            params=ChunkParams(initial_chunk=256 * 1024, large_chunk=MB),
-            zero_copy=False)
-        assert bytes(data) == blob
+        sink = {"buffer_sink": BufferSink(len(blob)),
+                "callable": callable_sink}.get(dest)
+        buf, report = asyncio.run(client.fetch(len(blob), sink, into=into))
+        assert sum(report.bytes_per_replica.values()) == len(blob)
+        if dest == "memory":
+            assert bytes(buf) == blob
+        elif dest == "into":
+            assert buf is into and bytes(into) == blob
+        elif dest == "buffer_sink":
+            assert buf is None and bytes(sink) == blob
+            assert sink.duplicate_bytes == 0
+            assert sink.covered_intervals() == [(0, len(blob))]
+        else:
+            assert buf is None and bytes(got) == blob
+            assert (times == 1).all()
     finally:
         s.stop()
 
